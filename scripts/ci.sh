@@ -1,10 +1,9 @@
 #!/usr/bin/env bash
 # Tier-1 CI gate: run the full suite with the src layout on PYTHONPATH.
 #
-# Policy (see src/repro/compat.py): the suite must COLLECT with zero
-# errors and report zero failures on the pinned toolchain even when
-# optional dev-deps (hypothesis) are absent — property tests skip, they
-# never break collection.
+# Policy: the suite must COLLECT with zero errors and report zero
+# failures on the pinned toolchain (requirements-ci.txt, hypothesis
+# included).
 #
 # Failure handling is exit-code-first: `set -e` aborts on any non-pytest
 # failure between the suite and the smoke (mktemp, the smoke invocation

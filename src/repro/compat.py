@@ -1,130 +1,27 @@
-"""Version-compatibility shims for the pinned toolchain.
+"""The one home of toolchain-specific spellings.
 
-The repo targets a range of jax releases whose public spellings moved:
-
-* ``pltpu.TPUCompilerParams`` (jax <= 0.4.x) was renamed to
-  ``pltpu.CompilerParams`` (jax >= 0.5).
-* ``jax.experimental.shard_map.shard_map`` (jax <= 0.4.x) was promoted
-  to ``jax.shard_map`` (jax >= 0.6) with ``check_rep`` renamed to
-  ``check_vma`` and a new optional ``axis_names`` argument.
-* ``hypothesis`` is a dev-only dependency; when absent, property tests
-  must *skip* instead of breaking collection of the whole suite.
-
-Policy: feature-detect (never parse version strings), expose one
-canonical spelling here, and keep every call site on the canonical
-spelling so the next rename is a one-file fix.
+Written for the installed jax (0.9): Pallas TPU compiler params are
+``pltpu.CompilerParams`` and ``shard_map`` is ``jax.shard_map`` with
+``check_vma``. Every call site uses the names below, so the next rename
+is a one-file fix.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
 
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
-
 def tpu_compiler_params(**kwargs: Any):
-    """Construct the TPU Pallas compiler-params object under either name
-    (``TPUCompilerParams`` on jax <= 0.4.x, ``CompilerParams`` later),
-    dropping keyword arguments the installed class does not know."""
-    try:
-        params = inspect.signature(_COMPILER_PARAMS_CLS).parameters
-        kwargs = {k: v for k, v in kwargs.items() if k in params}
-    except (TypeError, ValueError):
-        pass
-    return _COMPILER_PARAMS_CLS(**kwargs)
+    """Pallas TPU compiler params. An unknown keyword is an error (the
+    class raises), never silently dropped."""
+    return pltpu.CompilerParams(**kwargs)
 
 
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-_shard_map_impl = getattr(jax, "shard_map", None)
-if _shard_map_impl is None:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-_SHARD_MAP_PARAMS = None
-try:
-    _SHARD_MAP_PARAMS = set(
-        inspect.signature(_shard_map_impl).parameters)
-except (TypeError, ValueError):
-    pass
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=None, **kwargs):
-    """Canonical (new-API) shard_map spelling, translated for old jax:
-    ``check_vma`` maps to ``check_rep`` and ``axis_names`` is dropped
-    when the installed shard_map predates them."""
-    kw: dict = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kwargs)
-    if _SHARD_MAP_PARAMS is not None:
-        if axis_names is not None and "axis_names" in _SHARD_MAP_PARAMS:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            if "check_vma" in _SHARD_MAP_PARAMS:
-                kw["check_vma"] = check_vma
-            elif "check_rep" in _SHARD_MAP_PARAMS:
-                kw["check_rep"] = check_vma
-    else:                                    # signature unknown: best effort
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-    return _shard_map_impl(f, **kw)
-
-
-# ---------------------------------------------------------------------------
-# Optional hypothesis: stand-ins that turn property tests into skips
-# ---------------------------------------------------------------------------
-try:
-    from hypothesis import HealthCheck, given, settings  # noqa: F401
-    from hypothesis import strategies as st  # noqa: F401
-    HAS_HYPOTHESIS = True
-except ImportError:  # pragma: no cover - exercised when dev-dep absent
-    HAS_HYPOTHESIS = False
-
-    class _AnyStrategy:
-        """Accepts any strategy construction (st.integers(...), st.data(),
-        ...) at decoration time; values are never drawn because the test
-        body is replaced by a skip."""
-
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    st = _AnyStrategy()  # type: ignore[assignment]
-
-    class HealthCheck:  # type: ignore[no-redef]
-        def __getattr__(self, name):
-            return name
-    HealthCheck = HealthCheck()  # type: ignore[assignment]
-
-    class settings:  # type: ignore[no-redef]
-        def __init__(self, *a, **k):
-            pass
-
-        def __call__(self, fn):
-            return fn
-
-        @staticmethod
-        def register_profile(*a, **k):
-            pass
-
-        @staticmethod
-        def load_profile(*a, **k):
-            pass
-
-    def given(*_a, **_k):  # type: ignore[misc]
-        def deco(fn):
-            def skipper():
-                import pytest
-                pytest.skip("hypothesis not installed")
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-        return deco
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=frozenset(),
+              check_vma=True):
+    """``jax.shard_map`` under the repository's one spelling."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names,
+                         check_vma=check_vma)

@@ -1,5 +1,5 @@
 """Jitted public wrapper for the chunk-attention kernel: padding to block
-multiples, optional batch vmap, and CPU-interpret fallback."""
+multiples and optional batch vmap."""
 from __future__ import annotations
 
 import functools
@@ -7,7 +7,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.chunk_attention.kernel import chunk_attention_pallas
+
+
+def _round_up(n, m):
+    return max(m, -(-n // m) * m)
 
 
 def _pad_axis(x, mult, axis, value=0):
@@ -31,9 +36,12 @@ def chunk_attention(q, k, v, q_pos, k_pos, k_chunk, *,
     q_pos [B,A], k_pos [B,S], k_chunk [B,S]. Optional ``q_seg``/``k_seg``
     ([B,A]/[B,S]) carry packed-request segment ids so several requests can
     share one sequence row without attending across each other.
-    Returns (out, mass)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    Returns (out, mass).
+
+    Tiles: a block never exceeds the rows it covers rounded up to a
+    multiple of 8, and rows are padded up to a whole number of blocks
+    (padding carries position -1), so every tile is sublane-aligned."""
+    interpret = interpret_default(interpret)
     squeeze = q.ndim == 3
     if squeeze:
         q, k, v = q[None], k[None], v[None]
@@ -47,8 +55,8 @@ def chunk_attention(q, k, v, q_pos, k_pos, k_chunk, *,
         q_seg = jnp.zeros((B, A0), jnp.int32)
     if k_seg is None:
         k_seg = jnp.zeros((B, k.shape[1]), jnp.int32)
-    bq = min(block_q, max(8, A0))
-    bk = min(block_k, max(8, k.shape[1]))
+    bq = min(block_q, _round_up(A0, 8))
+    bk = min(block_k, _round_up(k.shape[1], 8))
     q = _pad_axis(q, bq, 1)
     q_pos = _pad_axis(q_pos, bq, 1, -1)
     q_seg = _pad_axis(q_seg, bq, 1, -1)

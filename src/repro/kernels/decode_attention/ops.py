@@ -5,6 +5,7 @@ import functools
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.decode_attention.kernel import (
     decode_attention_pallas,
     paged_decode_attention_pallas,
@@ -16,10 +17,9 @@ from repro.kernels.decode_attention.kernel import (
 def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                      block_k: int = 256, interpret: bool | None = None):
     """q [B,H,D], k/v [B,S,Hkv,D], q_pos [B], k_pos [B,S] -> [B,H,D]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     fn = functools.partial(decode_attention_pallas, window=window,
-                           block_k=block_k, interpret=interpret)
+                           block_k=block_k,
+                           interpret=interpret_default(interpret))
     if q.ndim == 3:
         return jax.vmap(fn)(q, k, v, q_pos, k_pos)
     return fn(q, k, v, q_pos, k_pos)
@@ -33,8 +33,6 @@ def paged_decode_attention(q, k_blocks, v_blocks, kpos_blocks, block_rows,
     [NB, bs, Hkv, D] (the pool arena, in place), kpos_blocks [NB, bs],
     block_rows [B, NBmax] (-1 padded), q_pos [B] -> [B,H,D]. The kv
     tile is the pool block itself — no per-request gather is formed."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return paged_decode_attention_pallas(
         q, k_blocks, v_blocks, kpos_blocks, block_rows, q_pos,
-        window=window, interpret=interpret)
+        window=window, interpret=interpret_default(interpret))

@@ -48,7 +48,7 @@ def _kernel(x_ref, la_ref, b_ref, c_ref, y_ref, st_ref):
     st_ref[...] = st.T[None, None].astype(st_ref.dtype)  # [1,1,P,N]
 
 
-def ssd_intra_pallas(xdt, log_a, B_mat, C_mat, *, interpret: bool = True):
+def ssd_intra_pallas(xdt, log_a, B_mat, C_mat, *, interpret: bool = False):
     """Intra-chunk SSD. xdt [nC,L,H,P] (x pre-multiplied by dt),
     log_a [nC,L,H], B_mat/C_mat [nC,L,N].
 

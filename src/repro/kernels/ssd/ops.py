@@ -5,15 +5,15 @@ import functools
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.ssd.kernel import ssd_intra_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_intra(xdt, log_a, B_mat, C_mat, *, interpret: bool | None = None):
     """xdt [B,nC,L,H,P] or [nC,L,H,P]; see kernel.ssd_intra_pallas."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fn = functools.partial(ssd_intra_pallas, interpret=interpret)
+    fn = functools.partial(ssd_intra_pallas,
+                           interpret=interpret_default(interpret))
     if xdt.ndim == 5:
         return jax.vmap(fn)(xdt, log_a, B_mat, C_mat)
     return fn(xdt, log_a, B_mat, C_mat)

@@ -94,6 +94,8 @@ def parse_tenants(s):
 
 def main():
     args = make_parser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     spec = EngineSpec.from_args(args)
     eng = build_engine(spec)
     kb = KnowledgeBase(num_chunks=args.kb_chunks,

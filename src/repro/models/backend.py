@@ -90,15 +90,15 @@ view through the same kernel under ``compat.shard_map``. Both yield
 inert zero rows for masked slots (``decode_slot == -1``), like every
 other backend.
 
-Interpret-mode tiling rule
---------------------------
-On hosts without a TPU the Pallas kernels run in interpret mode,
-where cost scales with the *grid*, not the hardware: block sizes are
-therefore clamped to the test geometry (``block = min(block,
-max(8, dim))``) before padding, so a tiny-config CI run executes the
-real kernel body over a handful of tiles at bounded cost instead of
-streaming 128x128 hardware tiles. The clamp only ever shrinks blocks;
-production TPU shapes are untouched.
+Kernel tiling rule
+------------------
+The Pallas kernels run compiled on a TPU and in interpret mode on any
+other backend (``repro.kernels.interpret_default``). Row tiles are
+``min(block, round_up(rows, 8))`` and rows are padded up to whole tiles
+(padding carries position -1), so a tiny-config CPU run executes the
+real kernel body over a handful of tiles while every TPU tile stays
+sublane-aligned; heads ride in the lane axis or as whole trailing
+``[Hkv, D]`` blocks, never as a second-minor block of 1.
 
 Head-shard KV layout invariants
 -------------------------------
@@ -379,10 +379,14 @@ def _impl_paged_kernel(ctx, window, packed, q, k_all, v_all, kv_pos):
     scalar-prefetched index maps walk each request's block-id row and
     read K/V straight out of the pool twin — no gather of any kind.
     Online softmax reduces in block order, so this route is allclose
-    (not bitwise) to the oracle, mirroring ``kernel`` vs ``dense``."""
-    if (ctx.paged_block_rows is None or not ctx.paged_block_size
-            or k_all.ndim != 3 or ctx.collect_stats):
-        return _impl_paged(ctx, window, packed, q, k_all, v_all, kv_pos)
+    (not bitwise) to the oracle, mirroring ``kernel`` vs ``dense``.
+    Outside a paged decode step (prefill windows, dense operands) the
+    route stays on Pallas: it is the ``kernel`` backend."""
+    if ctx.paged_block_rows is None or k_all.ndim != 3:
+        return _impl_kernel(ctx, window, packed, q, k_all, v_all, kv_pos)
+    if not ctx.paged_block_size or ctx.collect_stats:
+        raise ValueError("paged_kernel decode needs paged_block_size and "
+                         "no attention statistics")
     from repro.kernels.decode_attention.ops import paged_decode_attention
     bs = ctx.paged_block_size
     NBf = k_all.shape[0]

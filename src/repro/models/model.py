@@ -149,9 +149,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> PyTree:
     groups = []
     for p, kind in enumerate(pattern):
         defs = _kind_defs(cfg, kind)
-        stacked = [make(defs) for _ in range(cfg.n_groups)]
-        groups.append(jax.tree.map(lambda *xs: jnp.stack(xs), *stacked)
-                      if cfg.n_groups else {})
+        # keys drawn group-major (as make(defs) per group would), but
+        # stacked leaf by leaf: at full width only one leaf's per-group
+        # draws are alive next to the stack, not a second copy of all
+        gkeys = [[next(keys) for _ in defs] for _ in range(cfg.n_groups)]
+        groups.append({
+            n: jnp.stack([_init_leaf(gk[j], s, i, dtype) for gk in gkeys])
+            for j, (n, (s, _, i)) in enumerate(defs.items())}
+            if cfg.n_groups else {})
     tail = [make(_kind_defs(cfg, cfg.layer_kinds[cfg.n_groups * len(pattern)
                                                  + i]))
             for i in range(cfg.n_tail)]

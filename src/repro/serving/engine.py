@@ -116,6 +116,10 @@ from repro.serving.request import Request, State
 from repro.serving.scheduler import Scheduler, SchedulerConfig
 
 
+# prefill-window backend of each decode-only (paged) route
+_PREFILL_IMPL = {"paged": "dense", "paged_kernel": "kernel"}
+
+
 def _bucket(n: int, b: int) -> int:
     return max(b, -(-n // b) * b)
 
@@ -228,21 +232,29 @@ class Engine:
                  paged_decode: bool = False,
                  mesh=None):
         self.cfg = cfg
-        self.params = params
         self.store = store
         # attention backend selection (models.backend.BACKENDS). None
         # keeps the legacy split: "dense" prefill windows, "auto"
         # decode. A serving mesh forces the "sharded" backend and a
         # matching head-sharded pool layout; the mesh must be installed
         # before the first trace of any jit root that runs under it.
+        # Params are replicated onto the mesh, so every shard reads its
+        # own copy instead of pulling from wherever init left them.
         self.mesh = mesh
         kv_shards = 1
         if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
             from repro.distributed.sharding import serving_kv_shards
             from repro.models import backend as AB
             kv_shards = serving_kv_shards(mesh, cfg)
             AB.set_serving_mesh(mesh)
-            attn_impl = "sharded"
+            params = jax.device_put(
+                params, NamedSharding(mesh, PartitionSpec()))
+            # the paged route keeps its kernel under the mesh (the
+            # backend runs it per shard); everything else goes sharded
+            if not (paged_decode and attn_impl == "paged_kernel"):
+                attn_impl = "sharded"
+        self.params = params
         self.attn_impl = attn_impl
         self.kv_shards = kv_shards
         # typed executor construction (serving.api.EngineSpec is the
@@ -263,7 +275,10 @@ class Engine:
                 DeprecationWarning, stacklevel=2)
             ek.update(executor_kwargs)
         if attn_impl is not None:
-            ek.setdefault("attn_impl", attn_impl)
+            # the paged routes only change decode: their prefill windows
+            # run the backend each reduces to (sharded under a mesh)
+            ek.setdefault("attn_impl", "sharded" if mesh is not None
+                          else _PREFILL_IMPL.get(attn_impl, attn_impl))
         self.executor = CacheCraftExecutor(cfg, params, store, **ek)
         self.scheduler = Scheduler(sched or SchedulerConfig())
         self.counters = ServingCounters()

@@ -8,20 +8,13 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+from hypothesis import HealthCheck, settings  # noqa: E402
 
-# hypothesis is a dev-only dependency: property tests must skip (not
-# break collection) when it is absent — repro.compat provides skipping
-# stand-ins for given/strategies/settings in that case.
-from repro.compat import HAS_HYPOTHESIS  # noqa: E402
-
-if HAS_HYPOTHESIS:
-    from hypothesis import HealthCheck, settings  # noqa: E402
-
-    settings.register_profile(
-        "ci", max_examples=20, deadline=None,
-        suppress_health_check=[HealthCheck.too_slow,
-                               HealthCheck.data_too_large])
-    settings.load_profile("ci")
+settings.register_profile(
+    "ci", max_examples=20, deadline=None,
+    suppress_health_check=[HealthCheck.too_slow,
+                           HealthCheck.data_too_large])
+settings.load_profile("ci")
 
 
 @pytest.fixture(scope="session")

@@ -10,9 +10,10 @@ implementations (the ISSUE 6 tentpole gates).
   another's logits by a single bit (no cross-segment attention leak)
 * decode-slot edge case — masked batch rows (positions == -1) stay
   inert and finite while the live row's logits match a 1-row decode
-* sharded — subprocess with 4 fake host devices: engine logits
-  bit-identical to single-device while per-device KV bytes and
-  attention FLOPs are strictly lower; head-indivisible meshes rejected
+* sharded — subprocess with 4 fake host devices: engine tokens equal
+  and logits within 1e-5 of single-device while per-device KV bytes
+  and attention FLOPs are strictly lower; head-indivisible meshes
+  rejected
 
 Kernel (Pallas interpret-mode) cases carry the ``kernel_interpret``
 marker: included in default local runs, split into their own required
@@ -183,9 +184,16 @@ def _run(code: str, timeout=900):
 
 def test_sharded_engine_bit_identical_and_cheaper():
     """End-to-end engine run, unsharded vs head-sharded over 4 fake
-    devices: identical output tokens, bit-identical traced decode
-    logits, and strictly lower per-device KV bytes + attention FLOPs
-    (the tensor-parallel conservation gate)."""
+    devices: identical output tokens, traced decode logits within 1e-5,
+    and strictly lower per-device KV bytes + attention FLOPs (the
+    tensor-parallel conservation gate).
+
+    Logits are compared within a bound, not bitwise: each shard runs
+    the attention einsums over H/4 heads, and XLA picks its reduction
+    blocking per operand shape, so a per-head dot can round differently
+    from the same head inside the full-width einsum (about 1e-6 on the
+    tiny preset's O(1) logits). The per-head math is still the same
+    math; only the summation order moves."""
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -231,7 +239,8 @@ assert len(e1.decode_trace) == len(e2.decode_trace) > 0
 for da, db in zip(e1.decode_trace, e2.decode_trace):
     assert set(da) == set(db)
     for rid in da:
-        assert np.array_equal(da[rid], db[rid]), rid   # BIT equality
+        np.testing.assert_allclose(da[rid], db[rid], rtol=1e-5, atol=1e-5,
+                                   err_msg=str(rid))
 b1 = e1.pool.peak_kv_bytes_per_device()
 b4 = e2.pool.peak_kv_bytes_per_device()
 f1 = e1.counters.attn_flops_device

@@ -10,9 +10,8 @@ import pytest
 # resolved; the tier1 job deselects the marker so the soft gate is the
 # only CI gate on these). Default local runs still include it.
 pytestmark = pytest.mark.kernel_interpret
-# canonical spelling: real hypothesis when installed, skipping stand-ins
-# otherwise (see repro.compat)
-from repro.compat import given, settings, st  # noqa: F401
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.kernels.chunk_attention.ops import chunk_attention
 from repro.kernels.chunk_attention.ref import chunk_attention_ref

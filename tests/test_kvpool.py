@@ -1,9 +1,8 @@
 """Paged KV pool invariants (hypothesis state-machine style)."""
 import numpy as np
 import pytest
-# canonical spelling: real hypothesis when installed, skipping stand-ins
-# otherwise (see repro.compat)
-from repro.compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.serving.kvpool import BlockTable, KVPool
 

@@ -17,13 +17,10 @@ partially-drawn reservation in one compound op) must preserve:
 * ``gather`` round-trips every written token's KV bit-exactly;
 * a CoW write never mutates a canonical run's bytes or another
   reader's gathered KV.
-
-Uses the compat ``hypothesis`` shim: skips cleanly when the dev-dep is
-absent, never breaks collection (see repro.compat).
 """
 import numpy as np
-
-from repro.compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.serving.kvpool import BlockTable, KVPool
 

@@ -1,9 +1,8 @@
 """Property tests for the Cache-Craft reusability metrics (§3.1-§3.2)."""
 import numpy as np
 import pytest
-# canonical spelling: real hypothesis when installed, skipping stand-ins
-# otherwise (see repro.compat)
-from repro.compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.core import scoring
 from repro.core.focus import FocusTracker, predict_focused_chunks

@@ -1,9 +1,8 @@
 """Metrics, RAG substrate, workload, planner, preloading math."""
 import numpy as np
 import pytest
-# canonical spelling: real hypothesis when installed, skipping stand-ins
-# otherwise (see repro.compat)
-from repro.compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.core.planner import build_plan
 from repro.core.preload import layerwise_schedule, preload_depth

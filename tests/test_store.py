@@ -1,9 +1,8 @@
 """Chunk store (NxM variants, f_r eviction) + tiered storage tests."""
 import numpy as np
 import pytest
-# canonical spelling: real hypothesis when installed, skipping stand-ins
-# otherwise (see repro.compat)
-from repro.compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.core.chunkstore import ChunkStore, chunk_hash
 from repro.core.scoring import ChunkScores

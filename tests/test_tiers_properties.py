@@ -15,14 +15,13 @@ Random interleavings of ``put``/``get``/``pin``/``unpin``/``delete``/
 * cancelled tickets retract their pending promotions.
 
 Runs the store workerless: ``drain`` serves the preload queue inline,
-so every interleaving is fully deterministic. Uses the compat
-``hypothesis`` shim (skips cleanly when the dev-dep is absent)."""
+so every interleaving is fully deterministic."""
 import os
 import tempfile
 
 import numpy as np
-
-from repro.compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.core.tiers import PrefetchTicket, TieredStore, tree_nbytes
 

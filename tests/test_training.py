@@ -8,9 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-# canonical spelling: real hypothesis when installed, skipping stand-ins
-# otherwise (see repro.compat)
-from repro.compat import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.configs import get_tiny
 from repro.training import checkpoint as ckpt
