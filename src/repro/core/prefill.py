@@ -31,34 +31,41 @@ from repro.core.tiers import CPU_TO_HBM_GBPS, SSD_GBPS, merge_load_infos
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope
+from repro.tracing import span, to_host
 
 
 def _bucket(n: int, b: int) -> int:
     return max(b, -(-n // b) * b)
 
 
-def inject_chunk_kv(cfg: ModelConfig, kv, rope_pos) -> Tuple[np.ndarray,
-                                                             np.ndarray]:
+def inject_chunk_kv(cfg: ModelConfig, kv, rope_pos, counters=None
+                    ) -> Tuple[np.ndarray, np.ndarray]:
     """Stored (de-roped) chunk-cache KV -> the exact bytes injected into
     a prefill layout at ``rope_pos``: float32 cast + RoPE at the target
     positions. Single source of truth shared by the executor's compute
     injection and the engine's canonical pool-run materialization — the
     zero-copy design's bit-equality REQUIRES both to produce identical
     bytes, so never fork this transform."""
-    k = np.asarray(apply_rope(
+    k = to_host(apply_rope(
         jnp.asarray(np.asarray(kv["k"], np.float32)),
-        jnp.asarray(rope_pos), cfg.rope_theta))
+        jnp.asarray(rope_pos), cfg.rope_theta), counters)
     return k, np.asarray(kv["v"], np.float32)
 
 
+# Jitted steps are named functions: the name is the module's name in a
+# profiler trace ("jit_<name>").
 @functools.lru_cache(maxsize=None)
 def _embed_fn(cfg):
-    return jax.jit(functools.partial(M.embed_tokens, cfg))
+    def embed(params, tokens):
+        return M.embed_tokens(cfg, params, tokens)
+    return jax.jit(embed)
 
 
 @functools.lru_cache(maxsize=None)
 def _head_fn(cfg):
-    return jax.jit(functools.partial(M.lm_head, cfg))
+    def lm_head(params, h):
+        return M.lm_head(cfg, params, h)
+    return jax.jit(lm_head)
 
 
 @functools.lru_cache(maxsize=None)
@@ -66,8 +73,9 @@ def _window_fn(cfg):
     @functools.partial(jax.jit,
                        static_argnames=("g0", "g1", "tail", "collect",
                                         "attn_impl"))
-    def fn(params, h, positions, chunk_ids, cache, slots, seg_ids, kv_seg,
-           pack_qidx, pack_kidx, g0, g1, tail, collect, attn_impl="dense"):
+    def prefill_window(params, h, positions, chunk_ids, cache, slots,
+                       seg_ids, kv_seg, pack_qidx, pack_kidx, g0, g1, tail,
+                       collect, attn_impl="dense"):
         ctx = M.Ctx(cfg=cfg, mode="partial", positions=positions,
                     chunk_ids=chunk_ids, collect_stats=collect,
                     attn_impl=attn_impl, slots=slots, seg_ids=seg_ids,
@@ -75,7 +83,7 @@ def _window_fn(cfg):
                     pack_kidx=pack_kidx)
         return M.run_stack(cfg, params, h, ctx, cache=cache,
                            collect_stats=collect, g0=g0, g1=g1, tail=tail)
-    return fn
+    return prefill_window
 
 
 @functools.lru_cache(maxsize=None)
@@ -92,11 +100,11 @@ def decode_fn(cfg, attn_impl="auto"):
     The engine recycles such rows in place on the next join instead of
     rebuilding the whole (B, S) batch."""
     @jax.jit
-    def fn(params, tokens, positions, cache, slots=None):
+    def decode_step(params, tokens, positions, cache, slots=None):
         out = M.decode_step(cfg, params, tokens, positions, cache,
                             decode_slot=slots, attn_impl=attn_impl)
         return out.logits, out.cache
-    return fn
+    return decode_step
 
 
 @functools.lru_cache(maxsize=None)
@@ -113,7 +121,8 @@ def paged_decode_fn(cfg, attn_impl="paged", block_size=0):
     same step it is written — exactly like the arena path. The cache is
     donated: the twin is large (the whole pool) and must not double."""
     @functools.partial(jax.jit, donate_argnums=(3,))
-    def fn(params, tokens, positions, cache, slots, rows, block_rows=None):
+    def paged_decode_step(params, tokens, positions, cache, slots, rows,
+                          block_rows=None):
         B, S = rows.shape
         col = jnp.where(slots >= 0, positions, S)
         rows = rows.at[jnp.arange(B), col].set(slots, mode="drop")
@@ -122,7 +131,7 @@ def paged_decode_fn(cfg, attn_impl="paged", block_size=0):
                             paged_rows=rows, paged_block_rows=block_rows,
                             paged_block_size=block_size)
         return out.logits, out.cache
-    return fn
+    return paged_decode_step
 
 
 @functools.lru_cache(maxsize=None)
@@ -136,7 +145,7 @@ def paged_sync_fn(cfg):
     P, G = len(cfg.pattern), cfg.n_groups
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def fn(cache, slots, k_upd, v_upd, pos_upd):
+    def paged_sync(cache, slots, k_upd, v_upd, pos_upd):
         nslots = (cache["groups"][0]["kp"].shape[1] if G
                   else cache["tail"][0]["kp"].shape[0])
         wslot = jnp.where(slots >= 0, slots, nslots)
@@ -161,7 +170,7 @@ def paged_sync_fn(cfg):
                 "ppos": c["ppos"].at[wslot].set(pos_upd, mode="drop"),
             })
         return out
-    return fn
+    return paged_sync
 
 
 # ---------------------------------------------------------------------------
@@ -222,27 +231,27 @@ def pack_paged_cache(cfg: ModelConfig, k_pool, v_pool, pos_pool):
     return {"groups": groups, "tail": tail}
 
 
-def unpack_cache(cfg: ModelConfig, cache) -> Tuple[np.ndarray, np.ndarray,
-                                                   np.ndarray]:
+def unpack_cache(cfg: ModelConfig, cache, counters=None
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Model cache (B=1) -> (k [L,S,Hkv,D], v, pos [S]) numpy arrays."""
     P, G = len(cfg.pattern), cfg.n_groups
     ks, vs = [], []
     pos = None
     if G:
-        stacked_k = [np.asarray(cache["groups"][p]["k"][:, 0])
+        stacked_k = [to_host(cache["groups"][p]["k"][:, 0], counters)
                      for p in range(P)]           # each [G, S, Hkv, D]
-        stacked_v = [np.asarray(cache["groups"][p]["v"][:, 0])
+        stacked_v = [to_host(cache["groups"][p]["v"][:, 0], counters)
                      for p in range(P)]
-        pos = np.asarray(cache["groups"][0]["pos"][0, 0])
+        pos = to_host(cache["groups"][0]["pos"][0, 0], counters)
         for g in range(G):
             for p in range(P):
                 ks.append(stacked_k[p][g])
                 vs.append(stacked_v[p][g])
     for i in range(cfg.n_tail):
-        ks.append(np.asarray(cache["tail"][i]["k"][0]))
-        vs.append(np.asarray(cache["tail"][i]["v"][0]))
+        ks.append(to_host(cache["tail"][i]["k"][0], counters))
+        vs.append(to_host(cache["tail"][i]["v"][0], counters))
         if pos is None:
-            pos = np.asarray(cache["tail"][i]["pos"][0])
+            pos = to_host(cache["tail"][i]["pos"][0], counters)
     return np.stack(ks), np.stack(vs), pos
 
 
@@ -295,7 +304,9 @@ class CacheCraftExecutor:
     """Binds (model config, params, chunk store) into a serving-side
     prefill engine. ``strategy``: any name registered in
     ``core.strategies.STRATEGIES`` (resolved at construction, so an
-    unknown name fails fast with the known list)."""
+    unknown name fails fast with the known list). ``counters`` (any
+    object with ``d2h_reads``/``d2h_bytes``, the engine's
+    ``ServingCounters``) counts the pass's device->host reads."""
 
     def __init__(self, cfg: ModelConfig, params, store: Optional[ChunkStore],
                  *, strategy: str = "cachecraft", use_focus: bool = True,
@@ -306,7 +317,8 @@ class CacheCraftExecutor:
                  force_recompute_fraction: Optional[float] = None,
                  layerwise_load: bool = False,
                  attn_impl: str = "dense",
-                 rng: Optional[np.random.Generator] = None):
+                 rng: Optional[np.random.Generator] = None,
+                 counters=None):
         if not cfg.supports_chunk_cache and store is not None:
             raise ValueError(
                 f"{cfg.name}: chunk-cache inapplicable (see DESIGN.md §6)")
@@ -334,6 +346,7 @@ class CacheCraftExecutor:
         # EMA of measured per-layer window compute (feeds Eq. 16)
         self._t_layer_s = 0.0
         self.rng = rng or np.random.default_rng(0)
+        self.counters = counters
         # jit caches are shared across ALL executor instances of the same
         # config (benches spin up many executors; fresh jit caches per
         # instance would recompile every window shape repeatedly)
@@ -368,363 +381,378 @@ class CacheCraftExecutor:
             return []
         cfg = self.cfg
         t_start = time.perf_counter()
-        plans = [build_plan(
-            self.store if self.strategy_obj.needs_store else None,
-            sys_t, chs, q_t, strategy=self.strategy_obj, rng=self.rng,
-            force_recompute_fraction=self.force_recompute_fraction)
-            for sys_t, chs, q_t in requests]
-        if self.strategy_obj.needs_deviation:
-            plans = [self._finalize_deviation_plan(p) for p in plans]
+        with span("cc.prefill.plan"):
+            plans = [build_plan(
+                self.store if self.strategy_obj.needs_store else None,
+                sys_t, chs, q_t, strategy=self.strategy_obj, rng=self.rng,
+                force_recompute_fraction=self.force_recompute_fraction)
+                for sys_t, chs, q_t in requests]
+            if self.strategy_obj.needs_deviation:
+                plans = [self._finalize_deviation_plan(p) for p in plans]
         R = len(plans)
 
-        L = cfg.num_layers
-        hkv, dh = cfg.num_kv_heads, cfg.head_dim_
-        offs = np.concatenate(
-            [[0], np.cumsum([p.total_len for p in plans])]).astype(np.int64)
-        # totals bucket coarsens under packing so the jit cache stays
-        # small when many different request combinations get packed
-        # together (single-request buckets are unchanged); the coarse
-        # padding only costs linear ops — attention runs block-diagonal
-        tot_bucket = self.bucket if R == 1 else \
-            max(8 * self.bucket, self.bucket * R)
-        blk_bucket = self.bucket if R == 1 else 2 * self.bucket
-        S = _bucket(int(offs[-1]), tot_bucket)
-        k_np = np.zeros((L, S, hkv, dh), np.float32)
-        v_np = np.zeros((L, S, hkv, dh), np.float32)
-        pos_layout = np.full(S, -1, np.int32)
-        seg_layout = np.full(S, -1, np.int32)
-        layout_sid = np.full(S, cfg.stats_chunks - 1, np.int32)
+        with span("cc.prefill.inject"):
+            L = cfg.num_layers
+            hkv, dh = cfg.num_kv_heads, cfg.head_dim_
+            offs = np.concatenate(
+                [[0], np.cumsum([p.total_len for p in plans])]
+            ).astype(np.int64)
+            # totals bucket coarsens under packing so the jit cache stays
+            # small when many different request combinations get packed
+            # together (single-request buckets are unchanged); the coarse
+            # padding only costs linear ops — attention runs block-diagonal
+            tot_bucket = self.bucket if R == 1 else \
+                max(8 * self.bucket, self.bucket * R)
+            blk_bucket = self.bucket if R == 1 else 2 * self.bucket
+            S = _bucket(int(offs[-1]), tot_bucket)
+            k_np = np.zeros((L, S, hkv, dh), np.float32)
+            v_np = np.zeros((L, S, hkv, dh), np.float32)
+            pos_layout = np.full(S, -1, np.int32)
+            seg_layout = np.full(S, -1, np.int32)
+            layout_sid = np.full(S, cfg.stats_chunks - 1, np.int32)
 
-        # --- inject cached chunk KV (RoPE re-applied at local positions) ---
-        # Eager mode loads each hit variant whole, synchronously, here.
-        # Layerwise mode defers the KV bytes: a StreamJob per hit starts
-        # background per-layer loads, and the bytes land right before
-        # the window that computes each layer (see the window loop).
-        load_modeled = np.zeros(R)
-        load_measured = np.zeros(R)
-        tier_hits: List[Dict[str, int]] = [
-            {"hbm": 0, "cpu": 0, "ssd": 0} for _ in range(R)]
-        stream_jobs: List[StreamJob] = []
-        for r, plan in enumerate(plans):
-            off = int(offs[r])
-            for d in plan.decisions:
-                if not d.is_hit:
-                    continue
-                span = np.arange(d.seg.start, d.seg.end, dtype=np.int32)
-                rope_pos = span if self.fix_rpe else \
-                    (np.arange(d.seg.length) + d.variant.scores.orig_start)
-                pos_layout[off + d.seg.start:off + d.seg.end] = \
-                    span if self.fix_causality \
-                    else (np.arange(d.seg.length) +
-                          d.variant.scores.orig_start)
-                self.store.record_use(d.variant, max(d.cfo, 1e-3))
-                if self.layerwise_load and d.variant.num_layers == L:
-                    stream_jobs.append(StreamJob(
-                        r=r, stream=LayerStream(self.store, d.variant),
-                        off=off, seg=d.seg, rope_pos=rope_pos))
-                    continue
-                kv, info = self.store.get_kv(d.variant)
-                if info is not None:
-                    load_modeled[r] += info.seconds_modeled
-                    load_measured[r] += info.seconds_measured
-                    tier_hits[r][info.tier] += 1
-                kc, vc = inject_chunk_kv(cfg, kv, rope_pos)
-                k_np[:, off + d.seg.start:off + d.seg.end] = kc
-                v_np[:, off + d.seg.start:off + d.seg.end] = vc
-            # key-side (layout) stat ids for the model's mass statistic
-            for seg in plan.segments:
-                layout_sid[off + seg.start:off + seg.end] = seg.stat_id
-            seg_layout[off:off + plan.total_len] = r
-
-        # Eq. 16 / Algorithm 2: size the preload depth from measured
-        # per-layer compute (EMA over past passes) vs estimated
-        # per-layer load cost summed over streams (one worker serves
-        # them in series), then kick off the first lp layers in the
-        # background while the pass finishes setting up.
-        schedule = None
-        trace_windows: List[tuple] = []
-        if stream_jobs:
-            t_load_layer = sum(self._layer_load_estimate(j.stream.var)
-                               for j in stream_jobs)
-            schedule = layerwise_schedule(L, self._t_layer_s, t_load_layer)
-            # the first lp layers preload before execution starts —
-            # layer-major across streams, so the worker (FIFO) serves
-            # every stream's layer 0 before anyone's layer 1
-            for l in range(min(L, schedule.depth)):
-                for job in stream_jobs:
-                    job.stream.request([l])
-        layout_sid_j = jnp.asarray(layout_sid)[None]
-        kv_seg_j = jnp.asarray(seg_layout)[None]
-
-        # --- active rows (padded to bucket; row_map -> packed index) -------
-        n_acts = [p.num_active_tokens for p in plans]
-        act_offs = np.concatenate([[0], np.cumsum(n_acts)]).astype(np.int64)
-        n_act_total = int(act_offs[-1])
-        A = _bucket(n_act_total, tot_bucket)
-        act_tok = np.zeros(A, np.int32)
-        act_pos = np.full(A, -1, np.int32)
-        act_slot = np.full(A, -1, np.int32)
-        act_seg = np.full(A, -1, np.int32)
-        act_sid = np.full(A, cfg.stats_chunks - 1, np.int32)
-        row_map = np.full(A, -1, np.int64)
-        for r, plan in enumerate(plans):
-            a0, a1 = int(act_offs[r]), int(act_offs[r + 1])
-            act_tok[a0:a1] = plan.active_tokens
-            act_pos[a0:a1] = plan.active_positions
-            act_slot[a0:a1] = plan.active_positions + int(offs[r])
-            act_seg[a0:a1] = r
-            act_sid[a0:a1] = plan.active_stat_ids
-            row_map[a0:a1] = np.arange(a0, a1)
-
-        hit_ids = [{d.seg.stat_id for d in p.decisions
-                    if d.is_hit and len(d.recompute_idx) > 0}
-                   for p in plans]
-        trackers = [FocusTracker(len(plans[r].decisions), w=self.focus_w)
-                    if (self.use_focus and hit_ids[r] - {0}) else None
-                    for r in range(R)]
-        P, G = len(cfg.pattern), cfg.n_groups
-        w_groups = max(1, -(-self.focus_w // P)) \
-            if any(t is not None for t in trackers) else max(1, G)
-        if stream_jobs:
-            # layer-granular streaming needs narrow windows: every
-            # window boundary is an await point, so computing one layer
-            # group at a time lets layers > i + lp keep loading on the
-            # worker while group i computes (Algorithm 2's step loop)
-            w_groups = 1
-
-        h = self._embed(self.params, jnp.asarray(act_tok)[None])
-        positions = jnp.asarray(act_pos)[None]
-        slots = jnp.asarray(act_slot)[None]
-        seg_ids = jnp.asarray(act_seg)[None]
-        sid_np = act_sid.copy()
-        seg_np = act_seg.copy()
-
-        # block-diagonal gather maps: per-request query rows (recomputed
-        # after focus drops) and KV slots (static layout) so attention
-        # runs on [R, Amax] x [R, Smax] blocks, not the (sum A)(sum S)
-        # cross-request product
-        def _qidx_map():
-            if R == 1:
-                return None
-            rows = [np.where(seg_np == r)[0] for r in range(R)]
-            amax = _bucket(max(max(len(x) for x in rows), 1), blk_bucket)
-            out = np.full((R, amax), -1, np.int64)
-            for r, x in enumerate(rows):
-                out[r, :len(x)] = x
-            return jnp.asarray(out)
-
-        pack_qidx = _qidx_map()
-        pack_kidx = None
-        if R > 1:
-            smax = _bucket(max(p.total_len for p in plans), blk_bucket)
-            kidx = np.full((R, smax), -1, np.int64)
+            # --- inject cached chunk KV (RoPE re-applied at local positions)
+            # Eager mode loads each hit variant whole, synchronously, here.
+            # Layerwise mode defers the KV bytes: a StreamJob per hit starts
+            # background per-layer loads, and the bytes land right before
+            # the window that computes each layer (see the window loop).
+            load_modeled = np.zeros(R)
+            load_measured = np.zeros(R)
+            tier_hits: List[Dict[str, int]] = [
+                {"hbm": 0, "cpu": 0, "ssd": 0} for _ in range(R)]
+            stream_jobs: List[StreamJob] = []
             for r, plan in enumerate(plans):
-                kidx[r, :plan.total_len] = np.arange(
-                    int(offs[r]), int(offs[r]) + plan.total_len)
-            pack_kidx = jnp.asarray(kidx)
-        cache = pack_cache(cfg, k_np, v_np, pos_layout)
-        stats_all = np.zeros((L, n_act_total, cfg.stats_chunks), np.float32) \
-            if collect_stats else None
-        kstats_all = np.zeros((L, S), np.float32) if collect_stats else None
-        rows_layers = np.zeros(R, np.int64)
-        focus_cutoff: List[Optional[int]] = [None] * R
-        focused: List[Optional[set]] = [None] * R
-        chunk_stat_ids = [list(range(1, len(p.decisions))) for p in plans]
-
-        # window starts: groups in steps of w_groups, then the tail
-        starts = list(range(0, G, w_groups)) or [0]
-        layer_idx = 0
-        t_compute = 0.0
-        for wi, g0 in enumerate(starts):
-            g1 = min(G, g0 + w_groups)
-            is_last = wi == len(starts) - 1
-            nl = (g1 - g0) * P + (cfg.n_tail if is_last else 0)
-            if stream_jobs:
-                self._stage_window_layers(
-                    stream_jobs, schedule, cache, k_np, v_np,
-                    range(layer_idx, layer_idx + nl), trace_windows)
-            t_w0 = time.perf_counter()
-            h, new_cache, stats, kstats, _ = self._window(
-                self.params, h, positions, layout_sid_j, cache,
-                slots, seg_ids, kv_seg_j, pack_qidx, pack_kidx,
-                g0=g0, g1=g1, tail=is_last and cfg.n_tail > 0,
-                collect=collect_stats, attn_impl=self.attn_impl)
-            t_compute += time.perf_counter() - t_w0
-            live_pos = np.asarray(positions[0]) >= 0
-            for r in range(R):
-                rows_layers[r] += int((live_pos & (seg_np == r)).sum()) * nl
-            # write back updated cache slices
-            for p in range(P):
-                if g1 > g0:
-                    for name in ("k", "v", "pos"):
-                        cache["groups"][p][name] = \
-                            cache["groups"][p][name].at[g0:g1].set(
-                                new_cache["groups"][p][name])
-            if is_last and cfg.n_tail:
-                cache["tail"] = new_cache["tail"]
-            if collect_stats and stats is not None:
-                st = np.asarray(stats[:, 0])            # [nl, A_cur, C]
-                valid = row_map >= 0
-                stats_all[layer_idx:layer_idx + nl][:, row_map[valid]] = \
-                    st[:, valid]
-                if kstats is not None and kstats.shape[-1] == S:
-                    kstats_all[layer_idx:layer_idx + nl] += \
-                        np.asarray(kstats[:, 0])
-                # Algorithm 1 update from question-row mass, per request
-                newly_converged = []
-                for r, tracker in enumerate(trackers):
-                    if tracker is None or tracker.converged:
+                off = int(offs[r])
+                for d in plan.decisions:
+                    if not d.is_hit:
                         continue
-                    qrows = (sid_np == plans[r].question.stat_id) & \
-                        (seg_np == r)
-                    for li in range(st.shape[0]):
-                        qi = st[li][qrows][:, chunk_stat_ids[r]].sum(0)
-                        full_vec = np.zeros(len(plans[r].decisions))
-                        full_vec[chunk_stat_ids[r]] = qi
-                        if tracker.update(full_vec):
-                            break
-                    if tracker.converged:
-                        focus_cutoff[r] = tracker.cutoff_layer
-                        focused[r] = tracker.focused
-                        newly_converged.append(r)
-                if newly_converged and not is_last:
-                    drop = np.zeros(sid_np.shape[0], bool)
-                    pos_np = np.asarray(positions[0])
-                    for r in newly_converged:
-                        unfocused = (hit_ids[r] - {0}) - set(focused[r])
-                        if unfocused:
-                            drop |= np.isin(sid_np, list(unfocused)) & \
-                                (seg_np == r) & (pos_np >= 0) & \
-                                (sid_np != plans[r].question.stat_id)
-                    if drop.any() and R > 1:
-                        # packed batch: mask dropped rows IN PLACE (the
-                        # decode-row-masking template) — pos/slot -> -1
-                        # makes them attention-inert padding with their
-                        # KV writes dropped, while every array keeps its
-                        # shape, so heavy packing cannot mint a new jit
-                        # shape per newly-converged window. seg ids and
-                        # the block-diagonal qidx map stay as-is: masked
-                        # rows are skipped by the same pos >= 0 guards
-                        # that already skip bucket padding.
-                        pos2 = pos_np.copy()
-                        slot2 = np.asarray(slots[0]).copy()
-                        pos2[drop] = -1
-                        slot2[drop] = -1
-                        sid_np = sid_np.copy()
-                        sid_np[drop] = cfg.stats_chunks - 1
-                        row_map = row_map.copy()
-                        row_map[drop] = -1
-                        positions = jnp.asarray(pos2)[None]
-                        slots = jnp.asarray(slot2)[None]
-                    elif drop.any():
-                        # single request: re-bucket to a smaller active
-                        # set — the shrink saves real window compute and
-                        # the extra jit shape is bounded (R == 1)
-                        keep_idx = np.where(~drop & (row_map >= 0))[0]
-                        A2 = _bucket(len(keep_idx), tot_bucket)
-                        gather = np.zeros(A2, np.int64)
-                        gather[:len(keep_idx)] = keep_idx
-                        n_keep = len(keep_idx)
-                        h = jnp.asarray(np.asarray(h)[:, gather])
-                        pos2 = pos_np[gather]
-                        slot2 = np.asarray(slots[0])[gather]
-                        sid2 = sid_np[gather]
-                        seg2 = seg_np[gather]
-                        rm2 = row_map[gather]
-                        pos2[n_keep:] = -1
-                        slot2[n_keep:] = -1
-                        sid2[n_keep:] = cfg.stats_chunks - 1
-                        seg2[n_keep:] = -1
-                        rm2[n_keep:] = -1
-                        positions = jnp.asarray(pos2)[None]
-                        slots = jnp.asarray(slot2)[None]
-                        seg_ids = jnp.asarray(seg2)[None]
-                        sid_np = sid2
-                        seg_np = seg2
-                        row_map = rm2
-                        pack_qidx = _qidx_map()
-            layer_idx += nl
+                    seg_pos = np.arange(d.seg.start, d.seg.end,
+                                        dtype=np.int32)
+                    rope_pos = seg_pos if self.fix_rpe else \
+                        (np.arange(d.seg.length) + d.variant.scores.orig_start)
+                    pos_layout[off + d.seg.start:off + d.seg.end] = \
+                        seg_pos if self.fix_causality \
+                        else (np.arange(d.seg.length) +
+                              d.variant.scores.orig_start)
+                    self.store.record_use(d.variant, max(d.cfo, 1e-3))
+                    if self.layerwise_load and d.variant.num_layers == L:
+                        stream_jobs.append(StreamJob(
+                            r=r, stream=LayerStream(self.store, d.variant),
+                            off=off, seg=d.seg, rope_pos=rope_pos))
+                        continue
+                    kv, info = self.store.get_kv(d.variant)
+                    if info is not None:
+                        load_modeled[r] += info.seconds_modeled
+                        load_measured[r] += info.seconds_measured
+                        tier_hits[r][info.tier] += 1
+                    kc, vc = inject_chunk_kv(cfg, kv, rope_pos,
+                                             self.counters)
+                    k_np[:, off + d.seg.start:off + d.seg.end] = kc
+                    v_np[:, off + d.seg.start:off + d.seg.end] = vc
+                # key-side (layout) stat ids for the model's mass statistic
+                for seg in plan.segments:
+                    layout_sid[off + seg.start:off + seg.end] = seg.stat_id
+                seg_layout[off:off + plan.total_len] = r
 
-        # measured per-layer compute feeds the next pass's Eq. 16 depth
-        if L:
-            t_layer = t_compute / L
-            self._t_layer_s = t_layer if self._t_layer_s == 0.0 else \
-                0.5 * self._t_layer_s + 0.5 * t_layer
+            # Eq. 16 / Algorithm 2: size the preload depth from measured
+            # per-layer compute (EMA over past passes) vs estimated
+            # per-layer load cost summed over streams (one worker serves
+            # them in series), then kick off the first lp layers in the
+            # background while the pass finishes setting up.
+            schedule = None
+            trace_windows: List[tuple] = []
+            if stream_jobs:
+                t_load_layer = sum(self._layer_load_estimate(j.stream.var)
+                                   for j in stream_jobs)
+                schedule = layerwise_schedule(L, self._t_layer_s, t_load_layer)
+                # the first lp layers preload before execution starts —
+                # layer-major across streams, so the worker (FIFO) serves
+                # every stream's layer 0 before anyone's layer 1
+                for l in range(min(L, schedule.depth)):
+                    for job in stream_jobs:
+                        job.stream.request([l])
+            layout_sid_j = jnp.asarray(layout_sid)[None]
+            kv_seg_j = jnp.asarray(seg_layout)[None]
 
-        # streamed-load accounting: per-request modeled/measured totals
-        # (variant-level, deepest tier touched) plus the real overlap
-        # split — blocked seconds were measured at the await points
-        exposed_measured = np.zeros(R)
-        blocked_layers = np.zeros(R, np.int64)
-        hidden_layers = np.zeros(R, np.int64)
-        span_measured = np.zeros(R)
-        stream_traces: List[List[list]] = [[] for _ in range(R)]
-        req_infos: List[list] = [[] for _ in range(R)]
-        for job in stream_jobs:
-            s = job.stream
-            info = merge_load_infos(s._infos)
-            if info is not None:
-                load_modeled[job.r] += info.seconds_modeled
-                tier_hits[job.r][info.tier] += 1
-            req_infos[job.r].extend(s._infos)
-            exposed_measured[job.r] += s.blocked_seconds
-            blocked_layers[job.r] += s.blocked_layers
-            hidden_layers[job.r] += s.hidden_layers
-            stream_traces[job.r].append(list(s.trace))
-        for r, infos in enumerate(req_infos):
-            # measured time unions the [t0, t1) windows of EVERY layer
-            # load of the request across all its streams — per-layer
-            # loads run concurrently on the tier lanes, so summing
-            # per-stream merges would double-count overlapped wall time
-            # (and could report more measured load than elapsed time)
-            info = merge_load_infos(infos)
-            if info is not None:
-                load_measured[r] += info.seconds_measured
-        for r in range(R):
-            # wall-clock span of the request's loads (first request ->
-            # last completion): with parallel tier workers the summed
-            # per-load times overstate elapsed time, so overlap
-            # accounting clamps to this span
-            ts_all = [t for tr in stream_traces[r] for _ev, _l, t in tr]
-            if ts_all:
-                span_measured[r] = max(ts_all) - min(ts_all)
+            # --- active rows (padded to bucket; row_map -> packed index) ---
+            n_acts = [p.num_active_tokens for p in plans]
+            act_offs = np.concatenate(
+                [[0], np.cumsum(n_acts)]).astype(np.int64)
+            n_act_total = int(act_offs[-1])
+            A = _bucket(n_act_total, tot_bucket)
+            act_tok = np.zeros(A, np.int32)
+            act_pos = np.full(A, -1, np.int32)
+            act_slot = np.full(A, -1, np.int32)
+            act_seg = np.full(A, -1, np.int32)
+            act_sid = np.full(A, cfg.stats_chunks - 1, np.int32)
+            row_map = np.full(A, -1, np.int64)
+            for r, plan in enumerate(plans):
+                a0, a1 = int(act_offs[r]), int(act_offs[r + 1])
+                act_tok[a0:a1] = plan.active_tokens
+                act_pos[a0:a1] = plan.active_positions
+                act_slot[a0:a1] = plan.active_positions + int(offs[r])
+                act_seg[a0:a1] = r
+                act_sid[a0:a1] = plan.active_stat_ids
+                row_map[a0:a1] = np.arange(a0, a1)
 
-        # --- head: logits of each request's final question token -----------
-        last_rows = [int(np.where(row_map == int(act_offs[r + 1]) - 1)[0][0])
-                     for r in range(R)]
-        logits = self._head(self.params, h[:, np.asarray(last_rows)])
-        logits_np = np.asarray(logits[0])               # [R, V]
+            hit_ids = [{d.seg.stat_id for d in p.decisions
+                        if d.is_hit and len(d.recompute_idx) > 0}
+                       for p in plans]
+            trackers = [FocusTracker(len(plans[r].decisions), w=self.focus_w)
+                        if (self.use_focus and hit_ids[r] - {0}) else None
+                        for r in range(R)]
+            P, G = len(cfg.pattern), cfg.n_groups
+            w_groups = max(1, -(-self.focus_w // P)) \
+                if any(t is not None for t in trackers) else max(1, G)
+            if stream_jobs:
+                # layer-granular streaming needs narrow windows: every
+                # window boundary is an await point, so computing one layer
+                # group at a time lets layers > i + lp keep loading on the
+                # worker while group i computes (Algorithm 2's step loop)
+                w_groups = 1
 
-        k_fin, v_fin, pos_fin = unpack_cache(cfg, cache)
+            positions = jnp.asarray(act_pos)[None]
+            slots = jnp.asarray(act_slot)[None]
+            seg_ids = jnp.asarray(act_seg)[None]
+            sid_np = act_sid.copy()
+            seg_np = act_seg.copy()
+
+            # block-diagonal gather maps: per-request query rows (recomputed
+            # after focus drops) and KV slots (static layout) so attention
+            # runs on [R, Amax] x [R, Smax] blocks, not the (sum A)(sum S)
+            # cross-request product
+            def _qidx_map():
+                if R == 1:
+                    return None
+                rows = [np.where(seg_np == r)[0] for r in range(R)]
+                amax = _bucket(max(max(len(x) for x in rows), 1), blk_bucket)
+                out = np.full((R, amax), -1, np.int64)
+                for r, x in enumerate(rows):
+                    out[r, :len(x)] = x
+                return jnp.asarray(out)
+
+            pack_qidx = _qidx_map()
+            pack_kidx = None
+            if R > 1:
+                smax = _bucket(max(p.total_len for p in plans), blk_bucket)
+                kidx = np.full((R, smax), -1, np.int64)
+                for r, plan in enumerate(plans):
+                    kidx[r, :plan.total_len] = np.arange(
+                        int(offs[r]), int(offs[r]) + plan.total_len)
+                pack_kidx = jnp.asarray(kidx)
+            cache = pack_cache(cfg, k_np, v_np, pos_layout)
+            stats_all = np.zeros((L, n_act_total, cfg.stats_chunks),
+                                 np.float32) if collect_stats else None
+            kstats_all = np.zeros((L, S), np.float32) \
+                if collect_stats else None
+            rows_layers = np.zeros(R, np.int64)
+            focus_cutoff: List[Optional[int]] = [None] * R
+            focused: List[Optional[set]] = [None] * R
+            chunk_stat_ids = [list(range(1, len(p.decisions))) for p in plans]
+
+        with span("cc.prefill.compute"):
+            h = self._embed(self.params, jnp.asarray(act_tok)[None])
+            # window starts: groups in steps of w_groups, then the tail
+            starts = list(range(0, G, w_groups)) or [0]
+            layer_idx = 0
+            t_compute = 0.0
+            for wi, g0 in enumerate(starts):
+                g1 = min(G, g0 + w_groups)
+                is_last = wi == len(starts) - 1
+                nl = (g1 - g0) * P + (cfg.n_tail if is_last else 0)
+                if stream_jobs:
+                    self._stage_window_layers(
+                        stream_jobs, schedule, cache, k_np, v_np,
+                        range(layer_idx, layer_idx + nl), trace_windows)
+                t_w0 = time.perf_counter()
+                h, new_cache, stats, kstats, _ = self._window(
+                    self.params, h, positions, layout_sid_j, cache,
+                    slots, seg_ids, kv_seg_j, pack_qidx, pack_kidx,
+                    g0=g0, g1=g1, tail=is_last and cfg.n_tail > 0,
+                    collect=collect_stats, attn_impl=self.attn_impl)
+                t_compute += time.perf_counter() - t_w0
+                live_pos = to_host(positions[0], self.counters) >= 0
+                for r in range(R):
+                    rows_layers[r] += \
+                        int((live_pos & (seg_np == r)).sum()) * nl
+                # write back updated cache slices
+                for p in range(P):
+                    if g1 > g0:
+                        for name in ("k", "v", "pos"):
+                            cache["groups"][p][name] = \
+                                cache["groups"][p][name].at[g0:g1].set(
+                                    new_cache["groups"][p][name])
+                if is_last and cfg.n_tail:
+                    cache["tail"] = new_cache["tail"]
+                if collect_stats and stats is not None:
+                    st = to_host(stats[:, 0], self.counters)  # [nl, A, C]
+                    valid = row_map >= 0
+                    stats_all[layer_idx:layer_idx + nl][:, row_map[valid]] = \
+                        st[:, valid]
+                    if kstats is not None and kstats.shape[-1] == S:
+                        kstats_all[layer_idx:layer_idx + nl] += \
+                            to_host(kstats[:, 0], self.counters)
+                    # Algorithm 1 update from question-row mass, per request
+                    newly_converged = []
+                    for r, tracker in enumerate(trackers):
+                        if tracker is None or tracker.converged:
+                            continue
+                        qrows = (sid_np == plans[r].question.stat_id) & \
+                            (seg_np == r)
+                        for li in range(st.shape[0]):
+                            qi = st[li][qrows][:, chunk_stat_ids[r]].sum(0)
+                            full_vec = np.zeros(len(plans[r].decisions))
+                            full_vec[chunk_stat_ids[r]] = qi
+                            if tracker.update(full_vec):
+                                break
+                        if tracker.converged:
+                            focus_cutoff[r] = tracker.cutoff_layer
+                            focused[r] = tracker.focused
+                            newly_converged.append(r)
+                    if newly_converged and not is_last:
+                        drop = np.zeros(sid_np.shape[0], bool)
+                        pos_np = to_host(positions[0], self.counters)
+                        for r in newly_converged:
+                            unfocused = (hit_ids[r] - {0}) - set(focused[r])
+                            if unfocused:
+                                drop |= np.isin(sid_np, list(unfocused)) & \
+                                    (seg_np == r) & (pos_np >= 0) & \
+                                    (sid_np != plans[r].question.stat_id)
+                        if drop.any() and R > 1:
+                            # packed batch: mask dropped rows IN PLACE (the
+                            # decode-row-masking template) — pos/slot -> -1
+                            # makes them attention-inert padding with their
+                            # KV writes dropped, while every array keeps its
+                            # shape, so heavy packing cannot mint a new jit
+                            # shape per newly-converged window. seg ids and
+                            # the block-diagonal qidx map stay as-is: masked
+                            # rows are skipped by the same pos >= 0 guards
+                            # that already skip bucket padding.
+                            pos2 = pos_np.copy()
+                            slot2 = to_host(slots[0], self.counters).copy()
+                            pos2[drop] = -1
+                            slot2[drop] = -1
+                            sid_np = sid_np.copy()
+                            sid_np[drop] = cfg.stats_chunks - 1
+                            row_map = row_map.copy()
+                            row_map[drop] = -1
+                            positions = jnp.asarray(pos2)[None]
+                            slots = jnp.asarray(slot2)[None]
+                        elif drop.any():
+                            # single request: re-bucket to a smaller active
+                            # set — the shrink saves real window compute and
+                            # the extra jit shape is bounded (R == 1)
+                            keep_idx = np.where(~drop & (row_map >= 0))[0]
+                            A2 = _bucket(len(keep_idx), tot_bucket)
+                            gather = np.zeros(A2, np.int64)
+                            gather[:len(keep_idx)] = keep_idx
+                            n_keep = len(keep_idx)
+                            h = jnp.asarray(
+                                to_host(h, self.counters)[:, gather])
+                            pos2 = pos_np[gather]
+                            slot2 = to_host(slots[0], self.counters)[gather]
+                            sid2 = sid_np[gather]
+                            seg2 = seg_np[gather]
+                            rm2 = row_map[gather]
+                            pos2[n_keep:] = -1
+                            slot2[n_keep:] = -1
+                            sid2[n_keep:] = cfg.stats_chunks - 1
+                            seg2[n_keep:] = -1
+                            rm2[n_keep:] = -1
+                            positions = jnp.asarray(pos2)[None]
+                            slots = jnp.asarray(slot2)[None]
+                            seg_ids = jnp.asarray(seg2)[None]
+                            sid_np = sid2
+                            seg_np = seg2
+                            row_map = rm2
+                            pack_qidx = _qidx_map()
+                layer_idx += nl
+
+            # measured per-layer compute feeds the next pass's Eq. 16 depth
+            if L:
+                t_layer = t_compute / L
+                self._t_layer_s = t_layer if self._t_layer_s == 0.0 else \
+                    0.5 * self._t_layer_s + 0.5 * t_layer
+
+            # streamed-load accounting: per-request modeled/measured totals
+            # (variant-level, deepest tier touched) plus the real overlap
+            # split — blocked seconds were measured at the await points
+            exposed_measured = np.zeros(R)
+            blocked_layers = np.zeros(R, np.int64)
+            hidden_layers = np.zeros(R, np.int64)
+            span_measured = np.zeros(R)
+            stream_traces: List[List[list]] = [[] for _ in range(R)]
+            req_infos: List[list] = [[] for _ in range(R)]
+            for job in stream_jobs:
+                s = job.stream
+                info = merge_load_infos(s._infos)
+                if info is not None:
+                    load_modeled[job.r] += info.seconds_modeled
+                    tier_hits[job.r][info.tier] += 1
+                req_infos[job.r].extend(s._infos)
+                exposed_measured[job.r] += s.blocked_seconds
+                blocked_layers[job.r] += s.blocked_layers
+                hidden_layers[job.r] += s.hidden_layers
+                stream_traces[job.r].append(list(s.trace))
+            for r, infos in enumerate(req_infos):
+                # measured time unions the [t0, t1) windows of EVERY layer
+                # load of the request across all its streams — per-layer
+                # loads run concurrently on the tier lanes, so summing
+                # per-stream merges would double-count overlapped wall time
+                # (and could report more measured load than elapsed time)
+                info = merge_load_infos(infos)
+                if info is not None:
+                    load_measured[r] += info.seconds_measured
+            for r in range(R):
+                # wall-clock span of the request's loads (first request ->
+                # last completion): with parallel tier workers the summed
+                # per-load times overstate elapsed time, so overlap
+                # accounting clamps to this span
+                ts_all = [t for tr in stream_traces[r] for _ev, _l, t in tr]
+                if ts_all:
+                    span_measured[r] = max(ts_all) - min(ts_all)
+
+            # --- head: logits of each request's final question token ---
+            last_rows = [
+                int(np.where(row_map == int(act_offs[r + 1]) - 1)[0][0])
+                for r in range(R)]
+            logits = self._head(self.params, h[:, np.asarray(last_rows)])
+            logits_np = to_host(logits[0], self.counters)  # [R, V]
+
+        with span("cc.prefill.unpack"):
+            k_fin, v_fin, pos_fin = unpack_cache(cfg, cache,
+                                                 self.counters)
         wall = time.perf_counter() - t_start
-        results = []
-        for r, plan in enumerate(plans):
-            off, end = int(offs[r]), int(offs[r]) + plan.total_len
-            k_r = k_fin[:, off:end]
-            v_r = v_fin[:, off:end]
-            p_r = pos_fin[off:end]
-            if self.store is not None and collect_stats:
-                st_r = stats_all[:, int(act_offs[r]):int(act_offs[r + 1])]
-                ks_r = None if kstats_all is None else kstats_all[:, off:end]
-                self._capture(plan, st_r, ks_r, k_r, v_r)
-            streamed = any(j.r == r for j in stream_jobs)
-            results.append(PrefillResult(
-                plan=plan, logits_last=logits_np[r], k_layers=k_r,
-                v_layers=v_r, pos_layout=p_r, total_len=plan.total_len,
-                active_rows_layers=int(rows_layers[r]),
-                focus_cutoff=focus_cutoff[r], focused=focused[r],
-                load_seconds_modeled=float(load_modeled[r]),
-                load_seconds_measured=float(load_measured[r]),
-                tier_hits=tier_hits[r], wall_seconds=wall,
-                streamed=streamed,
-                load_exposed_measured=float(exposed_measured[r]),
-                load_span_measured=float(span_measured[r]),
-                load_blocked_layers=int(blocked_layers[r]),
-                load_hidden_layers=int(hidden_layers[r]),
-                preload_depth_used=schedule.depth if schedule else 0,
-                load_trace={"windows": list(trace_windows),
-                            "streams": stream_traces[r]}
-                if streamed else None))
+        with span("cc.prefill.capture"):
+            results = []
+            for r, plan in enumerate(plans):
+                off, end = int(offs[r]), int(offs[r]) + plan.total_len
+                k_r = k_fin[:, off:end]
+                v_r = v_fin[:, off:end]
+                p_r = pos_fin[off:end]
+                if self.store is not None and collect_stats:
+                    st_r = stats_all[:, int(act_offs[r]):int(act_offs[r + 1])]
+                    ks_r = None if kstats_all is None \
+                        else kstats_all[:, off:end]
+                    self._capture(plan, st_r, ks_r, k_r, v_r)
+                streamed = any(j.r == r for j in stream_jobs)
+                results.append(PrefillResult(
+                    plan=plan, logits_last=logits_np[r], k_layers=k_r,
+                    v_layers=v_r, pos_layout=p_r, total_len=plan.total_len,
+                    active_rows_layers=int(rows_layers[r]),
+                    focus_cutoff=focus_cutoff[r], focused=focused[r],
+                    load_seconds_modeled=float(load_modeled[r]),
+                    load_seconds_measured=float(load_measured[r]),
+                    tier_hits=tier_hits[r], wall_seconds=wall,
+                    streamed=streamed,
+                    load_exposed_measured=float(exposed_measured[r]),
+                    load_span_measured=float(span_measured[r]),
+                    load_blocked_layers=int(blocked_layers[r]),
+                    load_hidden_layers=int(hidden_layers[r]),
+                    preload_depth_used=schedule.depth if schedule else 0,
+                    load_trace={"windows": list(trace_windows),
+                                "streams": stream_traces[r]}
+                    if streamed else None))
         return results
 
     # ---- CacheBlend deviation probe (strategy_obj.needs_deviation) --------
@@ -766,7 +794,7 @@ class CacheCraftExecutor:
                 (np.arange(d.seg.length) + d.variant.scores.orig_start)
             # probe-only read: the main pass records the actual use
             kv, _info = self.store.get_kv(d.variant)
-            kc, vc = inject_chunk_kv(cfg, kv, rope_pos)
+            kc, vc = inject_chunk_kv(cfg, kv, rope_pos, self.counters)
             k_np[:, d.seg.start:d.seg.end] = kc
             v_np[:, d.seg.start:d.seg.end] = vc
             if d.deferred:
@@ -791,13 +819,17 @@ class CacheCraftExecutor:
             dev = np.zeros(d.seg.length)
             for l in probe_layers:
                 if G:
-                    k_new = np.asarray(
-                        new_cache["groups"][l]["k"][0, 0, s0:s1])
-                    v_new = np.asarray(
-                        new_cache["groups"][l]["v"][0, 0, s0:s1])
+                    k_new = to_host(
+                        new_cache["groups"][l]["k"][0, 0, s0:s1],
+                        self.counters)
+                    v_new = to_host(
+                        new_cache["groups"][l]["v"][0, 0, s0:s1],
+                        self.counters)
                 else:
-                    k_new = np.asarray(new_cache["tail"][l]["k"][0, s0:s1])
-                    v_new = np.asarray(new_cache["tail"][l]["v"][0, s0:s1])
+                    k_new = to_host(new_cache["tail"][l]["k"][0, s0:s1],
+                                    self.counters)
+                    v_new = to_host(new_cache["tail"][l]["v"][0, s0:s1],
+                                    self.counters)
                 dev += ((k_new - kc[l]) ** 2).sum(axis=(1, 2))
                 dev += ((v_new - vc[l]) ** 2).sum(axis=(1, 2))
             frac = self.force_recompute_fraction \
@@ -864,7 +896,7 @@ class CacheCraftExecutor:
                 # RoPE is layer-independent, so slicing commutes
                 kc, vc = inject_chunk_kv(
                     cfg, {"k": kv_l["k"][None], "v": kv_l["v"][None]},
-                    job.rope_pos)
+                    job.rope_pos, self.counters)
                 k_np[l, s0:s1] = kc[0]
                 v_np[l, s0:s1] = vc[0]
         # refresh the cache slices the window reads
@@ -920,10 +952,10 @@ class CacheCraftExecutor:
                                       tok_inter, token_total=tok_total,
                                       orig_start=d.seg.start)
             kv = {
-                "k": np.asarray(apply_rope(
+                "k": to_host(apply_rope(
                     jnp.asarray(k_fin[:, d.seg.start:d.seg.end]),
                     jnp.arange(d.seg.start, d.seg.end),
-                    cfg.rope_theta, inverse=True)),
+                    cfg.rope_theta, inverse=True), self.counters),
                 "v": v_fin[:, d.seg.start:d.seg.end].copy(),
             }
             self.store.add_variant(d.seg.chash, kv, sc)

@@ -120,6 +120,7 @@ def chunk_attention_pallas(q, k, v, q_pos, k_pos, k_chunk, *,
     k_row = pl.BlockSpec((1, block_k), lambda i, h, j: (0, j))
     out, mass = pl.pallas_call(
         kernel,
+        name="chunk_attention",
         grid=grid,
         in_specs=[
             q_col, q_col, k_row, k_row, k_row,

@@ -151,6 +151,7 @@ def paged_decode_attention_pallas(q, k_blocks, v_blocks, kpos_blocks,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=1.0 / np.sqrt(D),
                           window=window, num_kv_heads=Hkv),
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, NBmax),

@@ -114,6 +114,7 @@ from repro.serving.kvpool import KVPool
 from repro.serving.metrics import ServingCounters
 from repro.serving.request import Request, State
 from repro.serving.scheduler import Scheduler, SchedulerConfig
+from repro.tracing import span, to_host
 
 
 # prefill-window backend of each decode-only (paged) route
@@ -134,7 +135,7 @@ def _join_row_fn(cfg):
     P, G = len(cfg.pattern), cfg.n_groups
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def fn(cache, row, k, v, pos):
+    def decode_join_row(cache, row, k, v, pos):
         out = {"groups": [], "tail": []}
         if G:
             kg = k[:G * P].reshape((G, P) + k.shape[1:])
@@ -154,7 +155,7 @@ def _join_row_fn(cfg):
                 "pos": t["pos"].at[row].set(pos),
             })
         return out
-    return fn
+    return decode_join_row
 
 
 @functools.lru_cache(maxsize=None)
@@ -165,7 +166,7 @@ def _leave_row_fn(cfg):
     G = cfg.n_groups
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def fn(cache, row):
+    def decode_leave_row(cache, row):
         out = {"groups": [], "tail": []}
         if G:
             for c in cache["groups"]:
@@ -179,7 +180,7 @@ def _leave_row_fn(cfg):
                 "pos": t["pos"].at[row].set(-1),
             })
         return out
-    return fn
+    return decode_leave_row
 
 
 @dataclass
@@ -279,9 +280,10 @@ class Engine:
             # run the backend each reduces to (sharded under a mesh)
             ek.setdefault("attn_impl", "sharded" if mesh is not None
                           else _PREFILL_IMPL.get(attn_impl, attn_impl))
-        self.executor = CacheCraftExecutor(cfg, params, store, **ek)
-        self.scheduler = Scheduler(sched or SchedulerConfig())
         self.counters = ServingCounters()
+        self.executor = CacheCraftExecutor(cfg, params, store,
+                                           counters=self.counters, **ek)
+        self.scheduler = Scheduler(sched or SchedulerConfig())
         self.pool = KVPool(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_,
                            pool_blocks, block_size, counters=self.counters,
                            kv_shards=kv_shards)
@@ -465,6 +467,24 @@ class Engine:
     # ---- one ORCA iteration -------------------------------------------------
     def step(self) -> bool:
         """Returns True if any work was done."""
+        with span("cc.step"):
+            with span("cc.admit"):
+                reqs, worked = self._admission()
+            if reqs:
+                with span("cc.prefill", n=len(reqs),
+                          rids=" ".join(str(r.rid) for r in reqs)):
+                    self._run_prefills(reqs)
+                worked = True
+            if self.decoding:
+                self._run_decode_step()
+                worked = True
+            return worked
+
+    def _admission(self) -> Tuple[List[Request], bool]:
+        """The top of an iteration: cancels, queue expiry, look-ahead
+        prefetch and admission, with reclaim, preemption or the
+        shortage valve when the queue head cannot reserve. Returns the
+        admitted requests and whether any work was done."""
         worked = self._process_cancels()
         worked = self._expire_queued() or worked
         self._prefetch_lookahead()
@@ -532,13 +552,7 @@ class Engine:
                     worked = True
         elif reqs:
             self.scheduler.note_head_progress()
-        if reqs:
-            self._run_prefills(reqs)
-            worked = True
-        if self.decoding:
-            self._run_decode_step()
-            worked = True
-        return worked
+        return reqs, worked
 
     def _admit(self) -> List[Request]:
         return self.scheduler.next_prefills(
@@ -655,40 +669,44 @@ class Engine:
         self.stats.prefill_batch_max = max(self.stats.prefill_batch_max,
                                            len(reqs))
 
-        joined: List[Request] = []
-        for req, res in zip(reqs, results):
-            ok = self._write_back(req, res)
-            if not ok:
-                # copy path: unreachable with reserve-at-admission
-                # (counted so tests can assert 0). Zero-copy path: the
-                # delta estimate does not budget CoW clones, so a tight
-                # pool can fail the write-back — escalate the retry to
-                # a full reservation + copy-style write-back, which the
-                # reservation then covers by construction.
-                self.counters.burn_requeues += 1
-                req.reserve_full = True
-                self._requeue(req)
-                continue
-            first = int(np.argmax(res.logits_last[:self.cfg.vocab_size]))
-            self._count_attn_flops(res.plan.num_active_tokens,
-                                   res.total_len)
-            req.output_tokens.append(first)
-            self._emit_token(req, first)
-            req.total_len = res.total_len
-            req.t_first_token = self.clock
-            req.prefill_tokens_total = res.total_len
-            req.prefill_tokens_computed = res.plan.num_active_tokens
-            req.cache_hits = sum(d.is_hit for d in res.plan.decisions)
-            req.load_seconds_modeled = res.load_seconds_modeled
-            req.state = State.DECODING
-            self.stats.prefills += 1
-            self.stats.prefill_tokens_total += res.total_len
-            self.stats.prefill_tokens_computed += res.plan.num_active_tokens
-            self.counters.delta_blocks_saved += req.delta_blocks_saved
-            req.delta_blocks_saved = 0
-            self.decoding.append(req)
-            joined.append(req)
-        self._decode_join_batch(joined)
+        with span("cc.prefill.write_back"):
+            joined: List[Request] = []
+            for req, res in zip(reqs, results):
+                ok = self._write_back(req, res)
+                if not ok:
+                    # copy path: unreachable with reserve-at-admission
+                    # (counted so tests can assert 0). Zero-copy path:
+                    # the delta estimate does not budget CoW clones, so
+                    # a tight pool can fail the write-back — escalate
+                    # the retry to a full reservation + copy-style
+                    # write-back, which the reservation then covers by
+                    # construction.
+                    self.counters.burn_requeues += 1
+                    req.reserve_full = True
+                    self._requeue(req)
+                    continue
+                first = int(np.argmax(
+                    res.logits_last[:self.cfg.vocab_size]))
+                self._count_attn_flops(res.plan.num_active_tokens,
+                                       res.total_len)
+                req.output_tokens.append(first)
+                self._emit_token(req, first)
+                req.total_len = res.total_len
+                req.t_first_token = self.clock
+                req.prefill_tokens_total = res.total_len
+                req.prefill_tokens_computed = res.plan.num_active_tokens
+                req.cache_hits = sum(d.is_hit for d in res.plan.decisions)
+                req.load_seconds_modeled = res.load_seconds_modeled
+                req.state = State.DECODING
+                self.stats.prefills += 1
+                self.stats.prefill_tokens_total += res.total_len
+                self.stats.prefill_tokens_computed += \
+                    res.plan.num_active_tokens
+                self.counters.delta_blocks_saved += req.delta_blocks_saved
+                req.delta_blocks_saved = 0
+                self.decoding.append(req)
+                joined.append(req)
+            self._decode_join_batch(joined)
 
     # ---- zero-copy chunk sharing -------------------------------------------
     def _run_loader(self, variant, start: int, length: int):
@@ -704,9 +722,9 @@ class Engine:
             kv, _info = self.store.get_kv(variant)
             if kv is None:
                 return None
-            span = np.arange(start, start + length, dtype=np.int32)
-            k, v = inject_chunk_kv(self.cfg, kv, span)
-            return k, v, span
+            pos = np.arange(start, start + length, dtype=np.int32)
+            k, v = inject_chunk_kv(self.cfg, kv, pos, self.counters)
+            return k, v, pos
         return load
 
     def _write_back(self, req: Request, res) -> bool:
@@ -1046,14 +1064,18 @@ class Engine:
         k = np.zeros((cfg.num_layers, hkv, dh), np.float32)
         v = np.zeros((cfg.num_layers, hkv, dh), np.float32)
         for p in range(P):
-            kk = np.asarray(self._pcache["groups"][p]["kp"][:, slot])
-            vv = np.asarray(self._pcache["groups"][p]["vp"][:, slot])
+            kk = to_host(self._pcache["groups"][p]["kp"][:, slot],
+                         self.counters)
+            vv = to_host(self._pcache["groups"][p]["vp"][:, slot],
+                         self.counters)
             for g in range(G):
                 k[g * P + p] = kk[g]
                 v[g * P + p] = vv[g]
         for i in range(cfg.n_tail):
-            k[G * P + i] = np.asarray(self._pcache["tail"][i]["kp"][slot])
-            v[G * P + i] = np.asarray(self._pcache["tail"][i]["vp"][slot])
+            k[G * P + i] = to_host(self._pcache["tail"][i]["kp"][slot],
+                                   self.counters)
+            v[G * P + i] = to_host(self._pcache["tail"][i]["vp"][slot],
+                                   self.counters)
         return k, v
 
     def _run_decode_step_paged(self):
@@ -1074,161 +1096,172 @@ class Engine:
         host pool (``append_token`` cannot fail — the slot is open) and
         drop the block from the dirty log, since host and device now
         hold identical bytes."""
-        if self._dshape is None or self._needs_rebuild:
-            self._rebuild_decode_batch()
-        B, S = self._dshape
-        pslots = np.full(B, -1, np.int32)
-        for i, r in enumerate(list(self._rows)):
-            if r is None:
-                continue
-            s = self.pool.ensure_append_slot(r.table,
-                                             reservation=r.reservation)
-            if s is None:
-                # zero-copy: CoW fixups may have drained the delta
-                # reservation — escalate to a full reservation, same
-                # as the arena path's post-step append failure
-                r.reserve_full = True
-                self.decoding.remove(r)
-                self._decode_leave(i)
-                self._requeue(r)
-                continue
-            pslots[i] = s
-        if not self.decoding:
-            return
-        if self._pcache is None:
-            from repro.core.prefill import pack_paged_cache
-            self._pcache = pack_paged_cache(self.cfg,
-                                            *self.pool.block_view())
-            self.pool.clear_dirty(self.pool.dirty_blocks())
-        else:
-            self._sync_dirty_blocks()
-        toks = np.zeros(B, np.int32)
-        poss = np.full(B, -1, np.int32)
-        rows = np.full((B, S), -1, np.int32)
-        for i, r in enumerate(self._rows):
-            if r is None:
-                continue
-            toks[i] = r.output_tokens[-1]
-            poss[i] = r.total_len       # logical position (RoPE/causal)
-            rows[i] = self.pool.table_slot_index(r.table, S)
-        brows = None
-        if self._paged_kernel:
-            # the Pallas kernel iterates physical blocks, so it needs
-            # the block-id rows too (bucketed to bound retraces); all
-            # held blocks count — the pre-opened append block's unused
-            # slots carry pos == -1 and mask out in-kernel
-            nbm = _bucket(max(len(r.table.blocks) for r in self._rows
-                              if r is not None), 8)
-            brows = np.full((B, nbm), -1, np.int32)
+        with span("cc.decode.prepare"):
+            if self._dshape is None or self._needs_rebuild:
+                self._rebuild_decode_batch()
+            B, S = self._dshape
+            pslots = np.full(B, -1, np.int32)
+            for i, r in enumerate(list(self._rows)):
+                if r is None:
+                    continue
+                s = self.pool.ensure_append_slot(r.table,
+                                                 reservation=r.reservation)
+                if s is None:
+                    # zero-copy: CoW fixups may have drained the delta
+                    # reservation — escalate to a full reservation, same
+                    # as the arena path's post-step append failure
+                    r.reserve_full = True
+                    self.decoding.remove(r)
+                    self._decode_leave(i)
+                    self._requeue(r)
+                    continue
+                pslots[i] = s
+            if not self.decoding:
+                return
+            toks = np.zeros(B, np.int32)
+            poss = np.full(B, -1, np.int32)
+            rows = np.full((B, S), -1, np.int32)
             for i, r in enumerate(self._rows):
-                if r is not None:
-                    brows[i] = self.pool.table_block_row(r.table, nbm)
-        t0 = time.perf_counter()
-        logits, self._pcache = self._paged_fn(
-            self.params, jnp.asarray(toks), jnp.asarray(poss),
-            self._pcache, jnp.asarray(pslots), jnp.asarray(rows),
-            None if brows is None else jnp.asarray(brows))
-        logits = np.asarray(logits[:, 0])
-        self.clock += (time.perf_counter() - t0) * self.time_scale
-        self.stats.decode_steps += 1
-        self._count_attn_flops(B, S)
-        if self.trace_decode:
-            self.decode_trace.append(
-                {r.rid: logits[i].copy()
-                 for i, r in enumerate(self._rows) if r is not None})
-        for i, r in enumerate(list(self._rows)):
-            if r is None:
-                continue
-            nxt = int(np.argmax(logits[i, :self.cfg.vocab_size]))
-            ktok, vtok = self._extract_pool_slot_kv(int(pslots[i]))
-            self.pool.append_token(r.table, ktok, vtok, r.total_len,
-                                   reservation=r.reservation)
-            self.pool.clear_dirty([int(pslots[i])
-                                   // self.pool.block_size])
-            r.output_tokens.append(nxt)
-            self._emit_token(r, nxt)
-            r.total_len += 1
-            if len(r.output_tokens) >= r.max_new_tokens:
-                r.state = State.DONE
-                r.t_done = self.clock
-                self.stats.completed += 1
-                self.decoding.remove(r)
-                self._decode_leave(i)
-                if self.trace_decode:
-                    pad = _bucket(max(r.table.length, 1), self.seq_bucket)
-                    self.final_kv[r.rid] = self.pool.gather(r.table, pad)
-                self.pool.free_table(r.table)
-                self._release_runs(r)
-                self.pool.commit(r.reservation)
-                r.reservation = None
-                self.scheduler.on_terminal(r)
+                if r is None:
+                    continue
+                toks[i] = r.output_tokens[-1]
+                poss[i] = r.total_len       # logical position (RoPE/causal)
+                rows[i] = self.pool.table_slot_index(r.table, S)
+            brows = None
+            if self._paged_kernel:
+                # the Pallas kernel iterates physical blocks, so it needs
+                # the block-id rows too (bucketed to bound retraces); all
+                # held blocks count — the pre-opened append block's unused
+                # slots carry pos == -1 and mask out in-kernel
+                nbm = _bucket(max(len(r.table.blocks) for r in self._rows
+                                  if r is not None), 8)
+                brows = np.full((B, nbm), -1, np.int32)
+                for i, r in enumerate(self._rows):
+                    if r is not None:
+                        brows[i] = self.pool.table_block_row(r.table, nbm)
+        with span("cc.decode.sync"):
+            if self._pcache is None:
+                from repro.core.prefill import pack_paged_cache
+                self._pcache = pack_paged_cache(self.cfg,
+                                                *self.pool.block_view())
+                self.pool.clear_dirty(self.pool.dirty_blocks())
+            else:
+                self._sync_dirty_blocks()
+        with span("cc.decode.run"):
+            t0 = time.perf_counter()
+            logits, self._pcache = self._paged_fn(
+                self.params, jnp.asarray(toks), jnp.asarray(poss),
+                self._pcache, jnp.asarray(pslots), jnp.asarray(rows),
+                None if brows is None else jnp.asarray(brows))
+            logits = to_host(logits[:, 0], self.counters)
+            self.clock += (time.perf_counter() - t0) * self.time_scale
+            self.stats.decode_steps += 1
+            self._count_attn_flops(B, S)
+            if self.trace_decode:
+                self.decode_trace.append(
+                    {r.rid: logits[i].copy()
+                     for i, r in enumerate(self._rows) if r is not None})
+        with span("cc.decode.mirror"):
+            for i, r in enumerate(list(self._rows)):
+                if r is None:
+                    continue
+                nxt = int(np.argmax(logits[i, :self.cfg.vocab_size]))
+                ktok, vtok = self._extract_pool_slot_kv(int(pslots[i]))
+                self.pool.append_token(r.table, ktok, vtok, r.total_len,
+                                       reservation=r.reservation)
+                self.pool.clear_dirty([int(pslots[i])
+                                       // self.pool.block_size])
+                r.output_tokens.append(nxt)
+                self._emit_token(r, nxt)
+                r.total_len += 1
+                if len(r.output_tokens) >= r.max_new_tokens:
+                    r.state = State.DONE
+                    r.t_done = self.clock
+                    self.stats.completed += 1
+                    self.decoding.remove(r)
+                    self._decode_leave(i)
+                    if self.trace_decode:
+                        pad = _bucket(max(r.table.length, 1), self.seq_bucket)
+                        self.final_kv[r.rid] = self.pool.gather(r.table, pad)
+                    self.pool.free_table(r.table)
+                    self._release_runs(r)
+                    self.pool.commit(r.reservation)
+                    r.reservation = None
+                    self.scheduler.on_terminal(r)
 
     def _run_decode_step(self):
-        if self.paged_decode:
-            return self._run_decode_step_paged()
-        if self._dcache is None or self._needs_rebuild:
-            self._rebuild_decode_batch()
-        B, S = self._dshape
-        toks = np.zeros(B, np.int32)
-        poss = np.full(B, -1, np.int32)
-        slots = np.full(B, -1, np.int32)
-        for i, r in enumerate(self._rows):
-            if r is None:                  # masked row: inert (see
-                continue                   # decode_fn row-masking)
-            toks[i] = r.output_tokens[-1]
-            poss[i] = r.total_len          # logical position (RoPE/causal)
-            slots[i] = r.total_len         # arena append slot (compact
-            #   logical view; the pool's block-aligned slot is private
-            #   to append_token below)
-        t0 = time.perf_counter()
-        logits, self._dcache = self._decode_fn(
-            self.params, jnp.asarray(toks), jnp.asarray(poss), self._dcache,
-            jnp.asarray(slots))
-        logits = np.asarray(logits[:, 0])
-        self.clock += (time.perf_counter() - t0) * self.time_scale
-        self.stats.decode_steps += 1
-        self._count_attn_flops(B, S)
-        if self.trace_decode:
-            self.decode_trace.append(
-                {r.rid: logits[i].copy() for i, r in enumerate(self._rows)
-                 if r is not None})
+        with span("cc.decode", b=len(self.decoding)):
+            if self.paged_decode:
+                self._run_decode_step_paged()
+            else:
+                self._run_decode_step_arena()
 
-        for i, r in enumerate(list(self._rows)):
-            if r is None:
-                continue
-            nxt = int(np.argmax(logits[i, :self.cfg.vocab_size]))
-            # persist the newly written KV into the paged pool
-            ktok, vtok = self._extract_slot_kv(i, r.total_len)
-            if not self.pool.append_token(r.table, ktok, vtok,
-                                          r.total_len,
-                                          reservation=r.reservation):
-                # zero-copy: CoW fixups may have drained the delta
-                # reservation write_rows drew on — escalate the retry
-                # to a full reservation like the write-back burn path,
-                # so the request cannot exhaust retries and FAIL where
-                # the copy path would have served it
-                r.reserve_full = True
-                self.decoding.remove(r)
-                self._decode_leave(i)
-                self._requeue(r)
-                continue
-            r.output_tokens.append(nxt)
-            self._emit_token(r, nxt)
-            r.total_len += 1
-            if len(r.output_tokens) >= r.max_new_tokens:
-                r.state = State.DONE
-                r.t_done = self.clock
-                self.stats.completed += 1
-                self.decoding.remove(r)
-                self._decode_leave(i)
-                if self.trace_decode:
-                    pad = _bucket(max(r.table.length, 1), self.seq_bucket)
-                    self.final_kv[r.rid] = self.pool.gather(r.table, pad)
-                self.pool.free_table(r.table)
-                self._release_runs(r)
-                self.pool.commit(r.reservation)
-                r.reservation = None
-                self.scheduler.on_terminal(r)
+    def _run_decode_step_arena(self):
+        with span("cc.decode.prepare"):
+            if self._dcache is None or self._needs_rebuild:
+                self._rebuild_decode_batch()
+            B, S = self._dshape
+            toks = np.zeros(B, np.int32)
+            poss = np.full(B, -1, np.int32)
+            slots = np.full(B, -1, np.int32)
+            for i, r in enumerate(self._rows):
+                if r is None:                  # masked row: inert (see
+                    continue                   # decode_fn row-masking)
+                toks[i] = r.output_tokens[-1]
+                poss[i] = r.total_len          # logical position (RoPE/causal)
+                slots[i] = r.total_len         # arena append slot (compact
+                #   logical view; the pool's block-aligned slot is private
+                #   to append_token below)
+        with span("cc.decode.run"):
+            t0 = time.perf_counter()
+            logits, self._dcache = self._decode_fn(
+                self.params, jnp.asarray(toks), jnp.asarray(poss),
+                self._dcache, jnp.asarray(slots))
+            logits = to_host(logits[:, 0], self.counters)
+            self.clock += (time.perf_counter() - t0) * self.time_scale
+            self.stats.decode_steps += 1
+            self._count_attn_flops(B, S)
+            if self.trace_decode:
+                self.decode_trace.append(
+                    {r.rid: logits[i].copy() for i, r in enumerate(self._rows)
+                     if r is not None})
+        with span("cc.decode.mirror"):
+            for i, r in enumerate(list(self._rows)):
+                if r is None:
+                    continue
+                nxt = int(np.argmax(logits[i, :self.cfg.vocab_size]))
+                # persist the newly written KV into the paged pool
+                ktok, vtok = self._extract_slot_kv(i, r.total_len)
+                if not self.pool.append_token(r.table, ktok, vtok,
+                                              r.total_len,
+                                              reservation=r.reservation):
+                    # zero-copy: CoW fixups may have drained the delta
+                    # reservation write_rows drew on — escalate the retry
+                    # to a full reservation like the write-back burn path,
+                    # so the request cannot exhaust retries and FAIL where
+                    # the copy path would have served it
+                    r.reserve_full = True
+                    self.decoding.remove(r)
+                    self._decode_leave(i)
+                    self._requeue(r)
+                    continue
+                r.output_tokens.append(nxt)
+                self._emit_token(r, nxt)
+                r.total_len += 1
+                if len(r.output_tokens) >= r.max_new_tokens:
+                    r.state = State.DONE
+                    r.t_done = self.clock
+                    self.stats.completed += 1
+                    self.decoding.remove(r)
+                    self._decode_leave(i)
+                    if self.trace_decode:
+                        pad = _bucket(max(r.table.length, 1), self.seq_bucket)
+                        self.final_kv[r.rid] = self.pool.gather(r.table, pad)
+                    self.pool.free_table(r.table)
+                    self._release_runs(r)
+                    self.pool.commit(r.reservation)
+                    r.reservation = None
+                    self.scheduler.on_terminal(r)
 
     def _extract_slot_kv(self, batch_idx: int, slot: int):
         cfg = self.cfg
@@ -1238,18 +1271,18 @@ class Engine:
         k = np.zeros((L, hkv, dh), np.float32)
         v = np.zeros((L, hkv, dh), np.float32)
         for p in range(P):
-            kk = np.asarray(self._dcache["groups"][p]["k"]
-                            [:, batch_idx, slot])
-            vv = np.asarray(self._dcache["groups"][p]["v"]
-                            [:, batch_idx, slot])
+            kk = to_host(self._dcache["groups"][p]["k"]
+                         [:, batch_idx, slot], self.counters)
+            vv = to_host(self._dcache["groups"][p]["v"]
+                         [:, batch_idx, slot], self.counters)
             for g in range(G):
                 k[g * P + p] = kk[g]
                 v[g * P + p] = vv[g]
         for i in range(cfg.n_tail):
-            k[G * P + i] = np.asarray(
-                self._dcache["tail"][i]["k"][batch_idx, slot])
-            v[G * P + i] = np.asarray(
-                self._dcache["tail"][i]["v"][batch_idx, slot])
+            k[G * P + i] = to_host(
+                self._dcache["tail"][i]["k"][batch_idx, slot], self.counters)
+            v[G * P + i] = to_host(
+                self._dcache["tail"][i]["v"][batch_idx, slot], self.counters)
         return k, v
 
     # ---- workload driver ------------------------------------------------------

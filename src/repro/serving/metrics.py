@@ -95,6 +95,9 @@ class ServingCounters:
     paged_sync_bytes: int = 0            # KV bytes those uploads moved —
     #     the honest block-granular transfer cost the paged layout pays
     #     instead of per-step whole-request gathers
+    # --- host syncs (engine thread, repro.tracing.to_host) ---
+    d2h_reads: int = 0                   # blocking device->host reads
+    d2h_bytes: int = 0                   # bytes those reads brought back
 
     def reset(self):
         for f in dataclasses.fields(self):

@@ -70,6 +70,7 @@ import numpy as np
 
 from repro.serving.metrics import tenant_rollups
 from repro.serving.request import Request
+from repro.tracing import span
 
 
 def _request_from_json(rid: int, body: dict) -> Request:
@@ -185,7 +186,8 @@ class CacheCraftServer:
             if self._stop.is_set() and self._inbox.empty():
                 return False
             try:
-                req = self._inbox.get(timeout=0.02)
+                with span("cc.idle"):
+                    req = self._inbox.get(timeout=0.02)
             except queue.Empty:
                 return not self._stop.is_set()
             eng.clock = max(eng.clock, self._now())
@@ -207,22 +209,23 @@ class CacheCraftServer:
         TTL, finished requests beyond the retention cap). All registry
         access happens under ``_lock`` because HTTP submit threads
         insert concurrently."""
-        events = self.engine.drain_tokens()
-        now = time.monotonic()
-        with self._lock:
-            for rid, tok in events:
-                q = self._streams.get(rid)
-                if q is not None:
-                    q.put(("token", tok))
-            done = [rid for rid, r in self._inflight.items()
-                    if r.finished]
-            for rid in done:
-                req = self._inflight.pop(rid)
-                q = self._streams.get(rid)
-                if q is not None:
-                    q.put(("done", req.state.value))
-                    self._done_at[rid] = now
-            self._gc_locked(now)
+        with span("cc.dispatch"):
+            events = self.engine.drain_tokens()
+            now = time.monotonic()
+            with self._lock:
+                for rid, tok in events:
+                    q = self._streams.get(rid)
+                    if q is not None:
+                        q.put(("token", tok))
+                done = [rid for rid, r in self._inflight.items()
+                        if r.finished]
+                for rid in done:
+                    req = self._inflight.pop(rid)
+                    q = self._streams.get(rid)
+                    if q is not None:
+                        q.put(("done", req.state.value))
+                        self._done_at[rid] = now
+                self._gc_locked(now)
 
     def _gc_locked(self, now: float):
         """Reap abandoned state (caller holds ``_lock``): stream
