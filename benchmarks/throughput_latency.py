@@ -314,8 +314,9 @@ def _paged_compare(cfg, params, kb, n_req):
     """Arena-gather decode vs block-table-native paged decode on the
     churny join/leave schedule: streamed tokens AND per-step decode
     logits bit-equal, while the paged engine moves strictly fewer
-    decode gather bytes (zero — its only decode-side traffic is the
-    dirty-block sync of freshly written pool blocks). Returns the
+    decode gather bytes (zero — its only decode-side traffic is block
+    writes into the pool twin: prefill blocks copied device to device,
+    decode-opened blocks uploaded from the dirty log). Returns the
     count-based gate numbers per mode and appends the trajectory to
     ``results/BENCH_paged.json``."""
     sched = SchedulerConfig(max_batch_tokens=100_000, max_decode_batch=4,

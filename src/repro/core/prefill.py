@@ -136,8 +136,9 @@ def paged_decode_fn(cfg, attn_impl="paged", block_size=0):
 
 @functools.lru_cache(maxsize=None)
 def paged_sync_fn(cfg):
-    """Jitted dirty-block upload into the pool-twin cache: host-side
-    pool writes (prefill write-back, CoW clones, recompute fixups) land
+    """Jitted dirty-block upload into the pool-twin cache: pool writes
+    only the host holds (shared-run loads, CoW clones, recompute fixups,
+    decode-opened blocks, prefills before the twin's first pack) land
     on the device twin as one scatter of the touched blocks' slots.
     ``slots [m]`` flat slot ids (-1 entries drop), ``k_upd/v_upd
     [L, m, Hkv, D]``, ``pos_upd [m]``. The cache is donated — the
@@ -171,6 +172,77 @@ def paged_sync_fn(cfg):
             })
         return out
     return paged_sync
+
+
+@functools.lru_cache(maxsize=None)
+def paged_twin_write_fn(cfg):
+    """Jitted device-side write of a prefill's KV into the pool-twin
+    cache: the packed prefill cache's K/V (``prefill_kv``: the ``k``/``v``
+    leaves of ``process_batch``'s cache, layout rows on axis -3) are
+    gathered at ``src [m]`` layout rows and scattered to ``slots [m]``
+    flat twin slots with positions ``pos_upd [m]``. ``src == -1`` writes
+    zero K/V (a block's tail padding, as ``KVPool.write_run`` leaves
+    it); ``slots == -1`` drops the entry. Only the three int32 rows cross
+    from the host. The twin is donated, as in ``paged_sync_fn``."""
+    P, G = len(cfg.pattern), cfg.n_groups
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def paged_twin_write(cache, prefill_kv, slots, src, pos_upd):
+        nslots = (cache["groups"][0]["kp"].shape[1] if G
+                  else cache["tail"][0]["kp"].shape[0])
+        wslot = jnp.where(slots >= 0, slots, nslots)
+        live = (src >= 0)[:, None, None]
+        rows = jnp.maximum(src, 0)
+
+        def take(x):                        # [..., S, Hkv, D] -> [..., m]
+            return jnp.where(live, jnp.take(x, rows, axis=-3), 0)
+        out = {"groups": [], "tail": []}
+        if G:
+            posg = jnp.broadcast_to(pos_upd, (G,) + pos_upd.shape)
+            for p in range(P):
+                c, s = cache["groups"][p], prefill_kv["groups"][p]
+                out["groups"].append({
+                    "kp": c["kp"].at[:, wslot].set(take(s["k"][:, 0]),
+                                                   mode="drop"),
+                    "vp": c["vp"].at[:, wslot].set(take(s["v"][:, 0]),
+                                                   mode="drop"),
+                    "ppos": c["ppos"].at[:, wslot].set(posg, mode="drop"),
+                })
+        for i in range(cfg.n_tail):
+            c, s = cache["tail"][i], prefill_kv["tail"][i]
+            out["tail"].append({
+                "kp": c["kp"].at[wslot].set(take(s["k"][0]), mode="drop"),
+                "vp": c["vp"].at[wslot].set(take(s["v"][0]), mode="drop"),
+                "ppos": c["ppos"].at[wslot].set(pos_upd, mode="drop"),
+            })
+        return out
+    return paged_twin_write
+
+
+def prefill_kv(cache):
+    """The ``k``/``v`` leaves of a packed prefill cache (what
+    ``paged_twin_write_fn`` reads; positions come from the host)."""
+    return {"groups": [{"k": c["k"], "v": c["v"]} for c in cache["groups"]],
+            "tail": [{"k": t["k"], "v": t["v"]} for t in cache["tail"]]}
+
+
+def prefill_len(kv) -> int:
+    """Layout length ``S`` of a packed prefill cache (or its K/V)."""
+    return int(kv["groups"][0]["k"].shape[2] if kv["groups"]
+               else kv["tail"][0]["k"].shape[1])
+
+
+def zero_prefill_kv(cfg: ModelConfig, S: int):
+    """All-zero K/V in the packed prefill cache's layout (float32, as
+    ``process_batch`` packs it) at length ``S``: the stand-in that
+    compiles ``paged_twin_write_fn`` ahead of use."""
+    P, G = len(cfg.pattern), cfg.n_groups
+    hkv, dh = cfg.num_kv_heads, cfg.head_dim_
+    z = functools.partial(jnp.zeros, dtype=jnp.float32)
+    return {"groups": [{"k": z((G, 1, S, hkv, dh)), "v": z((G, 1, S, hkv, dh))}
+                       for _ in range(P if G else 0)],
+            "tail": [{"k": z((1, S, hkv, dh)), "v": z((1, S, hkv, dh))}
+                     for _ in range(cfg.n_tail)]}
 
 
 # ---------------------------------------------------------------------------
@@ -292,6 +364,12 @@ class PrefillResult:
     # trace for overlap assertions: {"windows": [(l0, l1, t_start)],
     #  "streams": [per-stream (event, layer, t) lists]}
     load_trace: Optional[dict] = None
+    # the pass's packed device KV (``prefill_kv``, shared by every
+    # request of the pass) and this request's layout offset in it: the
+    # source of the engine's device-side pool-twin write, which drops
+    # the reference once the write-back is done
+    device_kv: Optional[dict] = None
+    layout_off: int = 0
 
     @property
     def compute_fraction(self) -> float:
@@ -723,6 +801,7 @@ class CacheCraftExecutor:
             k_fin, v_fin, pos_fin = unpack_cache(cfg, cache,
                                                  self.counters)
         wall = time.perf_counter() - t_start
+        device_kv = prefill_kv(cache)
         with span("cc.prefill.capture"):
             results = []
             for r, plan in enumerate(plans):
@@ -752,7 +831,8 @@ class CacheCraftExecutor:
                     preload_depth_used=schedule.depth if schedule else 0,
                     load_trace={"windows": list(trace_windows),
                                 "streams": stream_traces[r]}
-                    if streamed else None))
+                    if streamed else None,
+                    device_kv=device_kv, layout_off=off))
         return results
 
     # ---- CacheBlend deviation probe (strategy_obj.needs_deviation) --------

@@ -105,7 +105,7 @@ import numpy as np
 
 from repro.core.chunkstore import ChunkStore, prompt_hashes
 from repro.core.prefill import CacheCraftExecutor, inject_chunk_kv, \
-    pack_cache
+    pack_cache, prefill_len, zero_prefill_kv
 from repro.core.preload import preload_depth
 from repro.core.tiers import PrefetchTicket
 from repro.models import model as M
@@ -123,6 +123,13 @@ _PREFILL_IMPL = {"paged": "dense", "paged_kernel": "kernel"}
 
 def _bucket(n: int, b: int) -> int:
     return max(b, -(-n // b) * b)
+
+
+def _sync_bucket(n: int) -> int:
+    """Blocks in one dirty-block upload: powers of two below 8, then
+    multiples of 8. The padding is uploaded too, so a lone
+    decode-opened block moves one block, not eight."""
+    return 1 << (n - 1).bit_length() if n < 8 else _bucket(n, 8)
 
 
 @functools.lru_cache(maxsize=None)
@@ -334,21 +341,28 @@ class Engine:
         # arenas, indexed per request by compact slot rows — joins and
         # leaves become row-map updates, rebuilds only re-bucket the
         # index tensor, and the new token's KV is scattered into its
-        # pre-opened pool slot inside the jitted pass. The twin stays
-        # coherent by uploading the pool's dirty-block log before each
-        # step (counted: paged_block_syncs / paged_sync_bytes), while
-        # the arena path's per-request copies land in
-        # decode_gather_bytes / decode_join_copies — ~0 here.
+        # pre-opened pool slot inside the jitted pass. Blocks a prefill
+        # fills from its own result (private segments, copy-mode
+        # writes) reach the twin on the device, gathered from the
+        # pass's device KV (twin_write_blocks / twin_write_bytes). Every
+        # other host write — shared-run loads, CoW clones, recompute
+        # fixup rows, decode-opened append blocks, prefills before the
+        # twin's first pack — stays in the pool's dirty-block log and is
+        # uploaded before the next step (paged_block_syncs /
+        # paged_sync_bytes), while the arena path's per-request copies
+        # land in decode_gather_bytes / decode_join_copies — ~0 here.
         self.paged_decode = bool(paged_decode)
         self._pcache = None
         self._paged_kernel = bool(paged_decode) and \
             self.attn_impl in ("paged_kernel",)
         if paged_decode:
-            from repro.core.prefill import paged_decode_fn, paged_sync_fn
+            from repro.core.prefill import paged_decode_fn, paged_sync_fn, \
+                paged_twin_write_fn
             impl = self.attn_impl \
                 if self.attn_impl in ("paged", "paged_kernel") else "paged"
             self._paged_fn = paged_decode_fn(cfg, impl, block_size)
             self._psync = paged_sync_fn(cfg)
+            self._twin_write = paged_twin_write_fn(cfg)
 
     # ---- submission ---------------------------------------------------------
     def submit(self, req: Request):
@@ -671,9 +685,13 @@ class Engine:
 
         with span("cc.prefill.write_back"):
             joined: List[Request] = []
+            twin_runs: List[tuple] = []
             for req, res in zip(reqs, results):
-                ok = self._write_back(req, res)
-                if not ok:
+                runs: List[tuple] = []
+                ok = self._write_back(req, res, runs)
+                if ok:
+                    twin_runs += runs
+                else:
                     # copy path: unreachable with reserve-at-admission
                     # (counted so tests can assert 0). Zero-copy path:
                     # the delta estimate does not budget CoW clones, so
@@ -707,6 +725,66 @@ class Engine:
                 self.decoding.append(req)
                 joined.append(req)
             self._decode_join_batch(joined)
+        if twin_runs and self._pcache is not None:
+            with span("cc.prefill.twin"):
+                self._write_twin(results[0].device_kv, twin_runs)
+        for res in results:
+            res.device_kv = None
+
+    def _write_twin(self, kv, runs: Sequence[tuple]):
+        """Fill freshly written pool blocks in the device twin from the
+        prefill pass's own device KV ``kv``: one jitted gather from the
+        packed layout and scatter into the twin, then the blocks leave
+        the dirty log, so ``_sync_dirty_blocks`` does not upload them.
+        ``runs`` are ``(blocks, layout_row, n, pos)``: ``n`` tokens from
+        packed row ``layout_row`` on, block-aligned into ``blocks``, the
+        tail padding zero K/V at position -1 exactly as ``write_run``
+        leaves the host pool."""
+        bs = self.pool.block_size
+        m = self._twin_map_len(prefill_len(kv), len(runs))
+        slots = np.full(m, -1, np.int32)
+        src = np.full(m, -1, np.int32)
+        pos = np.full(m, -1, np.int32)
+        i = 0
+        for blocks, row, n, p in runs:
+            ids = np.asarray(blocks, np.int64)
+            slots[i:i + ids.size * bs] = \
+                (ids[:, None] * bs + np.arange(bs)).reshape(-1)
+            src[i:i + n] = np.arange(row, row + n)
+            pos[i:i + n] = p
+            i += ids.size * bs
+        self._pcache = self._twin_write(
+            self._pcache, kv, jnp.asarray(slots), jnp.asarray(src),
+            jnp.asarray(pos))
+        ids = [b for blocks, *_ in runs for b in blocks]
+        self.pool.clear_dirty(ids)
+        self.counters.twin_write_blocks += len(ids)
+        self.counters.twin_write_bytes += len(ids) * self.pool.block_nbytes
+
+    def _twin_map_len(self, S: int, n_runs: int) -> int:
+        """Slots in a twin-write map: ``ceil(S / block)`` blocks plus the
+        run count bucketed to 8 (each run pads at most one block), so
+        any runs of a pass fit and the shape follows the prefill bucket
+        ``S`` alone while a pass has at most 8 runs."""
+        bs = self.pool.block_size
+        return (-(-S // bs) + _bucket(n_runs, 8)) * bs
+
+    def _warm_twin_programs(self):
+        """Compile, with all -1 maps that write nothing, the twin write
+        for every single-request prefill bucket up to ``seq_bucket`` and
+        the dirty-block upload of 1, 2, 4 and 8 blocks, so the first
+        real prefill of each length, and the first upload of a few
+        decode-opened blocks, run programs that are already built."""
+        b = self.executor.bucket
+        for S in range(b, max(b, self.seq_bucket) + 1, b):
+            none = jnp.full(self._twin_map_len(S, 1), -1, jnp.int32)
+            self._pcache = self._twin_write(
+                self._pcache, zero_prefill_kv(self.cfg, S), none, none, none)
+        k = self.pool.k
+        for m in sorted({_sync_bucket(n) for n in range(1, 9)}):
+            z = jnp.zeros((k.shape[0], m * k.shape[2]) + k.shape[3:], k.dtype)
+            none = jnp.full(m * k.shape[2], -1, jnp.int32)
+            self._pcache = self._psync(self._pcache, none, z, z, none)
 
     # ---- zero-copy chunk sharing -------------------------------------------
     def _run_loader(self, variant, start: int, length: int):
@@ -727,7 +805,7 @@ class Engine:
             return k, v, pos
         return load
 
-    def _write_back(self, req: Request, res) -> bool:
+    def _write_back(self, req: Request, res, runs: List[tuple]) -> bool:
         """Persist one prefill result into the request's block table.
 
         Copy mode: one dense ``write_prefill``. Zero-copy mode: segment
@@ -736,12 +814,19 @@ class Engine:
         (miss chunks, the question) gets fresh block-aligned segments.
         Non-recompute rows of a hit segment are never touched by the
         windowed pass, so shared-run bytes + per-request fixups
-        reproduce the copy path's KV exactly."""
+        reproduce the copy path's KV exactly. Every run of fresh blocks
+        filled straight from the result is appended to ``runs``, as
+        ``_write_twin`` takes them."""
         pool, plan = self.pool, res.plan
         if not self.share_chunk_kv or req.reserve_full:
-            return pool.write_prefill(req.table, res.k_layers,
+            if not pool.write_prefill(req.table, res.k_layers,
                                       res.v_layers, res.pos_layout,
-                                      reservation=req.reservation)
+                                      reservation=req.reservation):
+                return False
+            n = res.k_layers.shape[1]
+            runs.append((req.table.blocks[:pool.blocks_needed(n)],
+                         res.layout_off, n, res.pos_layout))
+            return True
         table = req.table
         for d in plan.decisions:
             seg = d.seg
@@ -770,19 +855,24 @@ class Engine:
                     continue
             # miss (or pin failed, e.g. variant evicted mid-batch):
             # private block-aligned copy of this segment's final KV
-            if pool.append_segment(
-                    table, res.k_layers[:, seg.start:seg.end],
-                    res.v_layers[:, seg.start:seg.end],
-                    res.pos_layout[seg.start:seg.end],
-                    reservation=req.reservation) is None:
+            if not self._append_private(req, res, seg, runs):
                 return False
         q = plan.question
-        if q.length == 0:
-            return True
-        return pool.append_segment(
-            table, res.k_layers[:, q.start:q.end],
-            res.v_layers[:, q.start:q.end], res.pos_layout[q.start:q.end],
-            reservation=req.reservation) is not None
+        return q.length == 0 or self._append_private(req, res, q, runs)
+
+    def _append_private(self, req: Request, res, seg, runs) -> bool:
+        """Fresh block-aligned segment ``seg`` of the result, appended
+        to the request's table and noted in ``runs``."""
+        table, n0 = req.table, len(req.table.blocks)
+        if self.pool.append_segment(
+                table, res.k_layers[:, seg.start:seg.end],
+                res.v_layers[:, seg.start:seg.end],
+                res.pos_layout[seg.start:seg.end],
+                reservation=req.reservation) is None:
+            return False
+        runs.append((table.blocks[n0:], res.layout_off + seg.start,
+                     seg.length, res.pos_layout[seg.start:seg.end]))
+        return True
 
     def _release_runs(self, req: Request):
         for run in req.shared_runs:
@@ -1023,18 +1113,21 @@ class Engine:
     def _sync_dirty_blocks(self):
         """Upload the pool's dirty-block log into the device twin: one
         jitted scatter of the touched blocks' flat slots (the id list
-        is bucketed so churny step counts do not retrace). Host writes
-        that dirty blocks — prefill write-back, CoW clones, recompute
-        fixup rows, freshly-opened append blocks — are exactly the
-        block-granular transfers a paged deployment pays, so they are
-        counted honestly (``paged_block_syncs`` / ``paged_sync_bytes``)
-        instead of hidden inside a wholesale re-pack."""
+        is bucketed, ``_sync_bucket``, so churny step counts do not
+        retrace). The log holds
+        the host writes whose bytes are not on the device already:
+        shared-run loads, CoW clones, recompute fixup rows and
+        freshly-opened append blocks — block-granular transfers a paged
+        deployment pays, counted honestly (``paged_block_syncs`` /
+        ``paged_sync_bytes``) instead of hidden inside a wholesale
+        re-pack. A prefill's private segments left the log when
+        ``_write_twin`` copied them on the device."""
         ids = self.pool.dirty_blocks()
         if not ids:
             return
         kp, vp, pp = self.pool.block_view()
         bs = self.pool.block_size
-        m = _bucket(len(ids), 8)
+        m = _sync_bucket(len(ids))
         bid = np.full(m, -1, np.int64)
         bid[:len(ids)] = ids
         slots = bid[:, None] * bs + np.arange(bs)[None, :]
@@ -1043,9 +1136,12 @@ class Engine:
         k = kp[:, idx].reshape(kp.shape[0], m * bs, *kp.shape[3:])
         v = vp[:, idx].reshape(vp.shape[0], m * bs, *vp.shape[3:])
         pos = np.where(slots >= 0, pp[idx].reshape(m * bs), -1)
+        # int32 on the host: a device-side cast would be one more
+        # program per bucket to compile
         self._pcache = self._psync(
-            self._pcache, jnp.asarray(slots, jnp.int32), jnp.asarray(k),
-            jnp.asarray(v), jnp.asarray(pos, jnp.int32))
+            self._pcache, jnp.asarray(slots.astype(np.int32)),
+            jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(pos.astype(np.int32)))
         self.counters.paged_block_syncs += len(ids)
         self.counters.paged_sync_bytes += int(
             kp[:, ids].nbytes + vp[:, ids].nbytes)
@@ -1145,6 +1241,7 @@ class Engine:
                 self._pcache = pack_paged_cache(self.cfg,
                                                 *self.pool.block_view())
                 self.pool.clear_dirty(self.pool.dirty_blocks())
+                self._warm_twin_programs()
             else:
                 self._sync_dirty_blocks()
         with span("cc.decode.run"):

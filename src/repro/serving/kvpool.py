@@ -117,8 +117,13 @@ no per-request gather, no dense row-arena copy. The pool exports:
   straight into the pool view at a statically-known flat slot.
 * ``dirty_blocks()`` / ``clear_dirty(ids)`` — a write log for keeping
   a device-side twin of the arenas coherent: every mutating op records
-  the block ids it touched; a consumer uploads exactly those blocks
-  and clears them.
+  the block ids it touched; a consumer brings exactly those blocks up
+  to date and clears them. The paged engine copies a prefill's private
+  segments (and copy-mode writes) into its twin on the device, from
+  the pass's own device KV, and clears them at once; what stays in the
+  log for the host->device upload is what only the host wrote —
+  shared-run loads, CoW clones, recompute fix-up rows, decode-opened
+  append blocks, and prefills that land before the twin exists.
 
 **Aliasing / CoW invariant** (what makes in-place reads safe): a block
 visible to more than one holder is NEVER mutated in place. Every write

@@ -89,12 +89,17 @@ class ServingCounters:
     decode_join_copies: int = 0          # joins that copied KV into a
     #     batch row (arena in-place joins); paged joins are row-map
     #     updates and count 0 here
-    paged_block_syncs: int = 0           # dirty pool blocks uploaded into
-    #     the device twin (host writes: prefill write-back, CoW clones,
-    #     recompute fixups) before a paged step
+    paged_block_syncs: int = 0           # dirty pool blocks uploaded
+    #     host->device into the twin before a paged step: shared-run
+    #     loads, CoW clones, recompute fixups, decode-opened append
+    #     blocks, and prefill blocks written before the twin existed
     paged_sync_bytes: int = 0            # KV bytes those uploads moved —
     #     the honest block-granular transfer cost the paged layout pays
     #     instead of per-step whole-request gathers
+    twin_write_blocks: int = 0           # pool blocks a prefill's private
+    #     segments (or copy-mode write) filled in the twin on the device,
+    #     from the pass's own device KV: no host upload
+    twin_write_bytes: int = 0            # KV bytes of those blocks
     # --- host syncs (engine thread, repro.tracing.to_host) ---
     d2h_reads: int = 0                   # blocking device->host reads
     d2h_bytes: int = 0                   # bytes those reads brought back

@@ -17,6 +17,8 @@ owns the device, records spans. Every name starts with ``cc.``::
         cc.prefill.unpack   unpack_cache (the packed KV to the host)
         cc.prefill.capture  store inserts
         cc.prefill.write_back  pool write-back, decode-batch joins
+        cc.prefill.twin     prefill blocks copied into the pool twin,
+                            device to device (paged decode)
       cc.decode             one decode step (b live rows)
         cc.decode.prepare   slot opening, rebuild, index and block rows
         cc.decode.sync      device twin upload (pack or dirty blocks)

@@ -29,7 +29,7 @@ PARENT = {
     "cc.prefill.plan": "cc.prefill", "cc.prefill.inject": "cc.prefill",
     "cc.prefill.compute": "cc.prefill", "cc.prefill.unpack": "cc.prefill",
     "cc.prefill.capture": "cc.prefill",
-    "cc.prefill.write_back": "cc.prefill",
+    "cc.prefill.write_back": "cc.prefill", "cc.prefill.twin": "cc.prefill",
     "cc.decode.prepare": "cc.decode", "cc.decode.sync": "cc.decode",
     "cc.decode.run": "cc.decode", "cc.decode.mirror": "cc.decode",
 }
