@@ -382,9 +382,9 @@ class CacheCraftExecutor:
     """Binds (model config, params, chunk store) into a serving-side
     prefill engine. ``strategy``: any name registered in
     ``core.strategies.STRATEGIES`` (resolved at construction, so an
-    unknown name fails fast with the known list). ``counters`` (any
-    object with ``d2h_reads``/``d2h_bytes``, the engine's
-    ``ServingCounters``) counts the pass's device->host reads."""
+    unknown name fails fast with the known list). ``counters`` (the
+    engine's ``ServingCounters``) counts the pass's device->host reads
+    and the rows its layer windows compute."""
 
     def __init__(self, cfg: ModelConfig, params, store: Optional[ChunkStore],
                  *, strategy: str = "cachecraft", use_focus: bool = True,
@@ -653,6 +653,10 @@ class CacheCraftExecutor:
                 for r in range(R):
                     rows_layers[r] += \
                         int((live_pos & (seg_np == r)).sum()) * nl
+                if self.counters is not None:
+                    self.counters.prefill_rows_run += live_pos.size * nl
+                    self.counters.prefill_rows_active += \
+                        int(live_pos.sum()) * nl
                 # write back updated cache slices
                 for p in range(P):
                     if g1 > g0:

@@ -103,6 +103,11 @@ class ServingCounters:
     # --- host syncs (engine thread, repro.tracing.to_host) ---
     d2h_reads: int = 0                   # blocking device->host reads
     d2h_bytes: int = 0                   # bytes those reads brought back
+    # --- prefill rows (CacheCraftExecutor.process_batch) ---
+    prefill_rows_run: int = 0            # rows each layer window computed,
+    #     bucket padding included, times the window's layers
+    prefill_rows_active: int = 0         # of those, rows that carry a
+    #     request's token, times the same layers
 
     def reset(self):
         for f in dataclasses.fields(self):

@@ -9,7 +9,7 @@ from repro.core.chunkstore import ChunkStore
 from repro.core.prefill import CacheCraftExecutor
 from repro.core.tiers import TieredStore
 from repro.models import model as M
-from repro.serving.metrics import relative_deviation
+from repro.serving.metrics import ServingCounters, relative_deviation
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +100,39 @@ def test_focus_early_termination_reduces_compute(world):
     if rf.focus_cutoff is not None and rf.focused is not None and \
             len(rf.focused) < 4:
         assert rf.active_rows_layers < rn.active_rows_layers
+
+
+def test_prefill_rows_counted_after_focus_rebucket(world, monkeypatch):
+    """A request whose focus drops rows re-buckets its later windows to
+    fewer rows: those windows count the smaller bucket they ran. The
+    tracker here focuses on the first chunk after one layer (random
+    weights spread attention too evenly for Algorithm 1 to drop any)."""
+    import repro.core.prefill as P
+    from repro.core.focus import FocusTracker
+
+    class FirstChunk(FocusTracker):
+        def update(self, inter_layer):
+            self.converged, self.focused, self.cutoff_layer = True, {1}, 0
+            return True
+    monkeypatch.setattr(P, "FocusTracker", FirstChunk)
+    cfg, params, kb, sys_t, q1, q2, _ = world
+    store = _store(world, "rows")
+    CacheCraftExecutor(cfg, params, store, use_focus=False).process(
+        sys_t, kb[:4], q1)
+    c = ServingCounters()
+    ex = CacheCraftExecutor(cfg, params, store, use_focus=True, focus_w=1,
+                            force_recompute_fraction=1.0,
+                            store_fixed_variants=False,
+                            store_new_chunks=False, counters=c)
+    r = ex.process(sys_t, kb[:4], q2)
+    L, b = cfg.num_layers, ex.bucket
+    n = r.plan.num_active_tokens                # 8 + 4 x 24 + 12 = 116
+    kept = n - 3 * 24                           # chunks 2-4 dropped
+    assert r.focused == {1} and n == 116
+    assert c.prefill_rows_active == r.active_rows_layers == \
+        n + kept * (L - 1)
+    assert c.prefill_rows_run == -(-n // b) * b + \
+        -(-kept // b) * b * (L - 1)
 
 
 def test_ablation_flags_change_output(world):

@@ -176,12 +176,18 @@ def test_paged_matches_arena_under_preemption(world):
     assert eng_p.pool.free_blocks == eng_p.pool.num_blocks
 
 
-def test_paged_kernel_backend_tracks_reference(world):
+@pytest.mark.parametrize("heads", [None, (8, 1)], ids=["tiny", "h8_kv1"])
+def test_paged_kernel_backend_tracks_reference(world, heads):
     """attn_impl="paged_kernel" routes the Pallas online-softmax kernel
     over pool blocks. Block-order accumulation differs from the dense
     reduction, so the gate is numerical closeness per step — plus the
-    same zero-gather counters."""
+    same zero-gather counters. The (8, 1) case is the 67B share's
+    grouped-query ratio: 8 query heads over one KV head, prefill then
+    up to 8 paged decode steps, logits compared."""
     cfg, params, kb = world
+    if heads is not None:
+        cfg = cfg.replace(num_heads=heads[0], num_kv_heads=heads[1])
+        params = M.init_params(cfg, jax.random.PRNGKey(1))
     eng_a, stats_a, _ = _run(cfg, params, kb, paged=False)
     eng_k, stats_k, _ = _run(cfg, params, kb, paged=True,
                              attn_impl="paged_kernel")

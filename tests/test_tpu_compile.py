@@ -1,5 +1,6 @@
-"""The main path's Pallas kernels compile for a TPU v5e at Llama-3-8B
-widths (32/8 heads of 128).
+"""The main path's Pallas kernels compile for a TPU v5e at head size
+128 with 32 query heads over 8 KV heads (Llama-3-8B's widths) and with
+8 over 1 (one chip's share of DeepSeek-LLM-67B, 8-way head-parallel).
 
 Each test compiles ahead of time for a described (not attached) v5e
 chip, which runs the TPU compiler's own checks — block tiling, VMEM,
@@ -16,7 +17,9 @@ from repro.kernels.decode_attention.ops import (decode_attention,
                                                 paged_decode_attention)
 from repro.kernels.rope.ops import rope
 
-H, HKV, D = 32, 8, 128
+D = 128
+HEADS = pytest.mark.parametrize("H,HKV", [(32, 8), (8, 1)],
+                                ids=["h32_kv8", "h8_kv1"])
 i32 = jnp.int32
 
 
@@ -45,7 +48,8 @@ def _compiled_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def test_chunk_attention_compiles(one_chip):
+@HEADS
+def test_chunk_attention_compiles(one_chip, H, HKV):
     A, S = 512, 2048
     text = _compiled_text(
         lambda *a: chunk_attention(*a, num_chunks=16, interpret=False),
@@ -54,7 +58,8 @@ def test_chunk_attention_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_decode_attention_compiles(one_chip):
+@HEADS
+def test_decode_attention_compiles(one_chip, H, HKV):
     B, S = 8, 2048
     text = _compiled_text(
         lambda *a: decode_attention(*a, interpret=False),
@@ -63,7 +68,8 @@ def test_decode_attention_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_attention_compiles(one_chip):
+@HEADS
+def test_paged_decode_attention_compiles(one_chip, H, HKV):
     B, NB, BS, NBMAX = 8, 1024, 16, 128
     text = _compiled_text(
         lambda *a: paged_decode_attention(*a, interpret=False),
@@ -73,7 +79,8 @@ def test_paged_decode_attention_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_rope_compiles(one_chip):
+@HEADS
+def test_rope_compiles(one_chip, H, HKV):
     T = 2048
     text = _compiled_text(
         lambda x, p: rope(x, p, theta=500_000.0, interpret=False),

@@ -179,6 +179,19 @@ def test_prefill_reads_are_counted(world):
     assert eng.stats_dict()["counters"]["d2h_reads"] == c.d2h_reads
 
 
+def test_prefill_rows_reach_stats(world):
+    """The prefill's row counters reach the engine's counters and
+    ``/stats``: one fresh 66-token prompt, every row computed, padded
+    to the executor's bucket in every layer."""
+    eng = _engine(world)
+    eng.submit(_requests(world[0], n=1, max_new=2)[0])
+    eng.step()
+    L, b = world[0].num_layers, eng.executor.bucket
+    d = eng.stats_dict()["counters"]
+    assert d["prefill_rows_active"] == 66 * L
+    assert d["prefill_rows_run"] == -(-66 // b) * b * L
+
+
 def test_span_with_profiler_off_records_nothing(tmp_path):
     with span("cc.before", n=1, rids="1 2"):
         pass
