@@ -119,7 +119,14 @@ def paged_decode_fn(cfg, attn_impl="paged", block_size=0):
     *existing* tokens; the appended token's slot is spliced in here at
     column ``positions`` (its logical index) so attention sees it the
     same step it is written — exactly like the arena path. The cache is
-    donated: the twin is large (the whole pool) and must not double."""
+    donated: the twin is large (the whole pool) and must not double.
+
+    Returns ``((logits, next_tokens, new_kv), cache)``: ``next_tokens
+    [B] int32`` is the argmax over the first ``vocab_size`` logits (the
+    first maximum, as ``np.argmax``), and ``new_kv [2, L, B, Hkv, D]``
+    is the K and V just written at each row's slot, read from the
+    output twin in the host pool's layer order (``_slot_kv``) — the
+    bytes ``KVPool.append_token`` mirrors. Masked rows read slot 0."""
     @functools.partial(jax.jit, donate_argnums=(3,))
     def paged_decode_step(params, tokens, positions, cache, slots, rows,
                           block_rows=None):
@@ -130,8 +137,25 @@ def paged_decode_fn(cfg, attn_impl="paged", block_size=0):
                             decode_slot=slots, attn_impl=attn_impl,
                             paged_rows=rows, paged_block_rows=block_rows,
                             paged_block_size=block_size)
-        return out.logits, out.cache
+        nxt = jnp.argmax(out.logits[:, 0, :cfg.vocab_size],
+                         axis=-1).astype(jnp.int32)
+        new_kv = _slot_kv(out.cache, jnp.maximum(slots, 0))
+        return (out.logits, nxt, new_kv), out.cache
     return paged_decode_step
+
+
+def _slot_kv(cache, slots):
+    """K and V at flat twin ``slots [B]`` as ``[2, L, B, Hkv, D]``:
+    layer ``g*P + p`` from ``groups[p]``, then ``G*P + i`` from
+    ``tail[i]``."""
+    parts = []
+    if cache["groups"]:
+        g = jnp.stack([jnp.stack([c["kp"][:, slots], c["vp"][:, slots]])
+                       for c in cache["groups"]], 2)   # [2, G, P, B, ..]
+        parts.append(g.reshape(2, -1, *g.shape[3:]))
+    parts += [jnp.stack([c["kp"][slots], c["vp"][slots]])[:, None]
+              for c in cache["tail"]]
+    return jnp.concatenate(parts, 1)
 
 
 @functools.lru_cache(maxsize=None)

@@ -1147,33 +1147,6 @@ class Engine:
             kp[:, ids].nbytes + vp[:, ids].nbytes)
         self.pool.clear_dirty(ids)
 
-    def _extract_pool_slot_kv(self, slot: int):
-        """Read one flat pool slot's per-layer KV back from the device
-        twin (the jitted pass scattered the new token there). This is
-        the host mirror's source, so host pool bytes and twin bytes
-        agree bit-for-bit by construction — which is what lets
-        ``append_token`` below clear the block's dirty mark instead of
-        re-uploading it next step."""
-        cfg = self.cfg
-        P, G = len(cfg.pattern), cfg.n_groups
-        hkv, dh = cfg.num_kv_heads, cfg.head_dim_
-        k = np.zeros((cfg.num_layers, hkv, dh), np.float32)
-        v = np.zeros((cfg.num_layers, hkv, dh), np.float32)
-        for p in range(P):
-            kk = to_host(self._pcache["groups"][p]["kp"][:, slot],
-                         self.counters)
-            vv = to_host(self._pcache["groups"][p]["vp"][:, slot],
-                         self.counters)
-            for g in range(G):
-                k[g * P + p] = kk[g]
-                v[g * P + p] = vv[g]
-        for i in range(cfg.n_tail):
-            k[G * P + i] = to_host(self._pcache["tail"][i]["kp"][slot],
-                                   self.counters)
-            v[G * P + i] = to_host(self._pcache["tail"][i]["vp"][slot],
-                                   self.counters)
-        return k, v
-
     def _run_decode_step_paged(self):
         """One decode iteration, block-table-native: attention reads
         K/V in place from the pool twin through per-request compact
@@ -1188,10 +1161,11 @@ class Engine:
         (2) bring the device twin up to date (initial wholesale pack,
         then dirty-block scatters); (3) run the jitted pass, which
         splices each row's pre-opened slot into its index row and
-        scatters the new token's KV there; (4) mirror that KV into the
-        host pool (``append_token`` cannot fail — the slot is open) and
-        drop the block from the dirty log, since host and device now
-        hold identical bytes."""
+        scatters the new token's KV there, and returns each row's argmax
+        token and that KV — both fetched in one read; (4) mirror the KV
+        into the host pool (``append_token`` cannot fail — the slot is
+        open) and drop the block from the dirty log, since host and
+        device now hold identical bytes."""
         with span("cc.decode.prepare"):
             if self._dshape is None or self._needs_rebuild:
                 self._rebuild_decode_batch()
@@ -1246,15 +1220,16 @@ class Engine:
                 self._sync_dirty_blocks()
         with span("cc.decode.run"):
             t0 = time.perf_counter()
-            logits, self._pcache = self._paged_fn(
+            (logits, nxt, new_kv), self._pcache = self._paged_fn(
                 self.params, jnp.asarray(toks), jnp.asarray(poss),
                 self._pcache, jnp.asarray(pslots), jnp.asarray(rows),
                 None if brows is None else jnp.asarray(brows))
-            logits = to_host(logits[:, 0], self.counters)
+            nxt, new_kv = to_host((nxt, new_kv), self.counters)
             self.clock += (time.perf_counter() - t0) * self.time_scale
             self.stats.decode_steps += 1
             self._count_attn_flops(B, S)
             if self.trace_decode:
+                logits = to_host(logits[:, 0], self.counters)
                 self.decode_trace.append(
                     {r.rid: logits[i].copy()
                      for i, r in enumerate(self._rows) if r is not None})
@@ -1262,14 +1237,14 @@ class Engine:
             for i, r in enumerate(list(self._rows)):
                 if r is None:
                     continue
-                nxt = int(np.argmax(logits[i, :self.cfg.vocab_size]))
-                ktok, vtok = self._extract_pool_slot_kv(int(pslots[i]))
-                self.pool.append_token(r.table, ktok, vtok, r.total_len,
+                self.pool.append_token(r.table, new_kv[0, :, i],
+                                       new_kv[1, :, i], r.total_len,
                                        reservation=r.reservation)
                 self.pool.clear_dirty([int(pslots[i])
                                        // self.pool.block_size])
-                r.output_tokens.append(nxt)
-                self._emit_token(r, nxt)
+                tok = int(nxt[i])
+                r.output_tokens.append(tok)
+                self._emit_token(r, tok)
                 r.total_len += 1
                 if len(r.output_tokens) >= r.max_new_tokens:
                     r.state = State.DONE

@@ -22,8 +22,10 @@ owns the device, records spans. Every name starts with ``cc.``::
       cc.decode             one decode step (b live rows)
         cc.decode.prepare   slot opening, rebuild, index and block rows
         cc.decode.sync      device twin upload (pack or dirty blocks)
-        cc.decode.run       the jitted step and the logits read
-        cc.decode.mirror    argmax, KV read-back, append, completion
+        cc.decode.run       the jitted step and its one read (paged:
+                            tokens and new KV; arena: the logits)
+        cc.decode.mirror    pool append, token emit, completion (arena:
+                            argmax and KV read-back too)
     cc.dispatch             the server's token fan-out
     cc.idle                 the server's blocking inbox wait
 """
@@ -39,11 +41,15 @@ def span(name: str, **args):
     return jax.profiler.TraceAnnotation(name, **args)
 
 
-def to_host(x, counters=None) -> np.ndarray:
-    """``np.asarray(x)``. A device array is a blocking device->host read:
-    counted in ``counters.d2h_reads`` / ``d2h_bytes`` when given."""
-    out = np.asarray(x)
-    if counters is not None and isinstance(x, jax.Array):
-        counters.d2h_reads += 1
-        counters.d2h_bytes += out.nbytes
-    return out
+def to_host(x, counters=None):
+    """``np.asarray(x)``, or a tuple of them fetched in one blocking
+    ``jax.device_get``. Each device array is a device->host read: counted
+    in ``counters.d2h_reads`` / ``d2h_bytes`` when given."""
+    xs = x if isinstance(x, tuple) else (x,)
+    out = tuple(np.asarray(a) for a in jax.device_get(xs))
+    if counters is not None:
+        for a, o in zip(xs, out):
+            if isinstance(a, jax.Array):
+                counters.d2h_reads += 1
+                counters.d2h_bytes += o.nbytes
+    return out if isinstance(x, tuple) else out[0]

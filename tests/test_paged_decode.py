@@ -176,6 +176,89 @@ def test_paged_matches_arena_under_preemption(world):
     assert eng_p.pool.free_blocks == eng_p.pool.num_blocks
 
 
+def _twin_slot_kv(cfg, twin, slot):
+    """Layer-ordered K and V ``[L, Hkv, D]`` at one flat twin slot."""
+    P, G = len(cfg.pattern), cfg.n_groups
+    k = [None] * cfg.num_layers
+    v = [None] * cfg.num_layers
+    for p, c in enumerate(twin["groups"]):
+        for g in range(G):
+            k[g * P + p] = c["kp"][g, slot]
+            v[g * P + p] = c["vp"][g, slot]
+    for i, c in enumerate(twin["tail"]):
+        k[G * P + i], v[G * P + i] = c["kp"][slot], c["vp"][slot]
+    return np.stack(k), np.stack(v)
+
+
+def test_paged_step_returns_tokens_and_new_kv(world):
+    """The paged step hands back each row's token and new KV in one
+    read. Over decode-only steps with 3 live rows beside a masked one,
+    on a config with two pattern layers, a tail layer and a padded
+    vocabulary whose padding columns would win an unmasked argmax: (a)
+    each emitted token is ``np.argmax`` of the step's logits over
+    ``vocab_size``; (b) the host pool's K/V at every slot the step wrote
+    equal the twin's bit for bit; (c) none of those blocks stays in the
+    dirty log."""
+    _cfg, _params, kb = world
+    cfg = _cfg.replace(vocab_size=500, pattern=("attn", "attn"),
+                       num_layers=5)
+    assert cfg.padded_vocab > cfg.vocab_size and cfg.n_tail == 1
+    params = M.init_params(cfg, jax.random.PRNGKey(2))
+    pad = 100 * jax.random.normal(
+        jax.random.PRNGKey(3), (cfg.d_model, cfg.padded_vocab - 500))
+    params["unembed"] = params["unembed"].at[:, 500:].set(pad)
+    spec = EngineSpec(
+        strategy="all", use_focus=False, pool_blocks=64,
+        decode_bucket_b=4, seq_bucket=512,
+        sched=SchedulerConfig(max_batch_tokens=100_000,
+                              max_decode_batch=4, max_prefill_batch=4),
+        trace_decode=True, paged_decode=True)
+    eng = build_engine(spec, cfg=cfg, params=params, store=None)
+    reqs = _churny_requests(kb, lengths=(12, 3, 12, 12))
+    for r in reqs:
+        eng.submit(r)
+    while eng.scheduler.queue:
+        eng.step()
+    pool, bs = eng.pool, eng.pool.block_size
+    written = []
+    append = pool.append_token
+
+    def recording_append(table, *a, **kw):
+        ok = append(table, *a, **kw)
+        n = table.length - 1
+        written.append(table.blocks[n // bs] * bs + n % bs)
+        return ok
+    pool.append_token = recording_append
+
+    masked_steps = padded_wins = 0
+    while eng.decoding:
+        before = {r.rid: len(r.output_tokens) for r in eng.decoding}
+        live = len(eng.decoding)
+        masked_steps += live >= 3 and None in eng._rows
+        written.clear()
+        eng.step()
+        assert not eng.scheduler.queue and len(written) == live
+        logits = eng.decode_trace[-1]
+        assert set(logits) == set(before)
+        for r in reqs:
+            if r.rid in before:
+                row = logits[r.rid]
+                assert r.output_tokens[before[r.rid]] == \
+                    int(np.argmax(row[:cfg.vocab_size]))
+                padded_wins += int(np.argmax(row)) >= cfg.vocab_size
+        twin = jax.tree.map(np.asarray, eng._pcache)
+        for slot in written:
+            b, off = divmod(slot, bs)
+            kt, vt = _twin_slot_kv(cfg, twin, slot)
+            np.testing.assert_array_equal(
+                pool.k[:, b, off].view(np.uint32), kt.view(np.uint32))
+            np.testing.assert_array_equal(
+                pool.v[:, b, off].view(np.uint32), vt.view(np.uint32))
+        assert not {s // bs for s in written} & set(pool.dirty_blocks())
+    assert masked_steps >= 2 and padded_wins > 0
+    assert all(r.state == State.DONE for r in reqs)
+
+
 @pytest.mark.parametrize("heads", [None, (8, 1)], ids=["tiny", "h8_kv1"])
 def test_paged_kernel_backend_tracks_reference(world, heads):
     """attn_impl="paged_kernel" routes the Pallas online-softmax kernel

@@ -3,8 +3,9 @@
 * An engine served under the JAX profiler records the ``cc.*`` span
   tree of ``repro.tracing``'s docstring, each span inside its parent,
   and the server's engine thread records ``cc.dispatch``/``cc.idle``.
-* Every blocking device->host read of a decode step is counted: the
-  logits, then the new token's K and V per pattern layer per live row.
+* Every device->host read of a decode step is counted: a paged step
+  fetches its tokens and new KV together; an arena step reads the
+  logits, then the new token's K and V per layer per live row.
 * With the profiler off a span records nothing.
 """
 import glob
@@ -148,21 +149,30 @@ def test_server_engine_thread_records_dispatch_and_idle(world, tmp_path):
 
 
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "arena"])
-def test_decode_step_reads_logits_then_kv_per_layer_per_row(world, paged):
+def test_decode_step_reads_two_arrays_paged_kv_per_row_arena(world, paged):
+    """A paged step makes one fetch of two arrays (tokens and new KV),
+    whatever the live rows and layers; an arena step reads the logits,
+    then K and V per pattern and tail layer per live row."""
     cfg, _ = world
     eng = _engine(world, paged=paged)
-    for r in _requests(cfg, n=3, max_new=8):
+    reqs = _requests(cfg, n=3, max_new=8)
+    for r, n in zip(reqs, (4, 6, 8)):
+        r.max_new_tokens = n
+    for r in reqs:
         eng.submit(r)
     while eng.scheduler.queue:                 # admit all three
         eng.step()
     P = len(cfg.pattern)
-    for _ in range(3):                         # decode-only steps
+    seen = set()
+    while eng.decoding:                        # decode-only steps
         live = len(eng.decoding)
-        assert live == 3 and not eng.scheduler.queue
+        seen.add(live)
+        assert not eng.scheduler.queue
         before = eng.counters.d2h_reads
         eng.step()
         assert eng.counters.d2h_reads - before == \
-            1 + 2 * (P + cfg.n_tail) * live
+            (2 if paged else 1 + 2 * (P + cfg.n_tail) * live)
+    assert seen == {1, 2, 3}
 
 
 def test_prefill_reads_are_counted(world):
@@ -216,3 +226,6 @@ def test_to_host_counts_device_reads_only():
     to_host(np.ones(3), c)                   # already on the host
     to_host(x)                               # no counters given
     assert (c.d2h_reads, c.d2h_bytes) == (1, 128)
+    a, b = to_host((x, jnp.arange(3, dtype=jnp.int32)), c)  # one fetch
+    assert a.shape == (4, 8) and b.tolist() == [0, 1, 2]
+    assert (c.d2h_reads, c.d2h_bytes) == (3, 128 + 128 + 12)
