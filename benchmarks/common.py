@@ -8,6 +8,7 @@ checkpoint is cached under results/bench_model/.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -117,10 +118,19 @@ def make_engine(cfg, params, store, **spec_kw):
                         store=store)
 
 
+@functools.lru_cache(maxsize=None)
+def _decode_step(cfg):
+    """Jitted one-token decode on a contiguous cache."""
+    @jax.jit
+    def decode_step(params, tokens, positions, cache):
+        out = M.decode_step(cfg, params, tokens, positions, cache)
+        return out.logits, out.cache
+    return decode_step
+
+
 def greedy_continue(cfg, params, res, n_tokens: int) -> List[int]:
     """Greedy decode continuing from an executor PrefillResult."""
-    from repro.core.prefill import decode_fn
-    step = decode_fn(cfg)
+    step = _decode_step(cfg)
     S = res.k_layers.shape[1]
     # k_layers is exact-length (total_len); leave room for every decode
     # write plus slack so no token scatter lands out of bounds

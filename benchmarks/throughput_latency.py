@@ -8,9 +8,6 @@ Also emitted:
 * ``fig22_admission_{serial,packed}`` — packed multi-request prefill vs
   serial admission under queue pressure (CI perf smoke asserts
   packed >= serial via ``--ci-smoke``).
-* ``fig22_decode_churn_{rebuild,incremental}`` — rebuild-on-any-change
-  decode batch vs in-place join/leave row maintenance under a churny
-  join/leave schedule (reservation + incremental-decode tentpole).
 * ``fig22_shared_blocks_{copy,zerocopy}`` — per-request KV copies vs
   zero-copy shared chunk blocks + delta-only admission on an
   overlapping-chunk workload (zero-copy tentpole).
@@ -32,15 +29,9 @@ Also emitted:
   attention FLOPs, with output tokens identical and traced decode
   logits bit-identical across the two runs. Trajectory appends to
   ``results/BENCH_sharded.json``.
-* ``fig22_paged_{arena,paged}`` — arena-gather decode vs
-  block-table-native paged decode on the churny join/leave schedule
-  (the paged-decode tentpole): streamed tokens and per-step decode
-  logits bit-equal while ``decode_gather_bytes`` and
-  ``decode_join_copies`` drop to zero (count-based). Trajectory
-  appends to ``results/BENCH_paged.json``.
 
-``--ci-smoke`` runs the perf gates (admission throughput, decode-churn
-rebuild *counts*, copy-vs-zerocopy reserved *blocks*, preemption
+``--ci-smoke`` runs the perf gates (admission throughput,
+copy-vs-zerocopy reserved *blocks*, preemption
 *counts* + logits bit-equality, eviction tier-miss *counts* (LRU vs
 reuse-aware, from ``benchmarks.preloading.eviction_compare``), the
 eager-vs-layerwise preload comparison (hidden/blocked layer counts +
@@ -71,7 +62,6 @@ from benchmarks.common import emit, fresh_store, get_trained_model, \
     make_engine, make_world, record_trajectory as _record_trajectory
 from repro.serving.engine import EngineStats
 from repro.serving.metrics import queue_wait_p99, ttft_p99
-from repro.serving.rag import KnowledgeBase
 from repro.serving.request import Request, State
 from repro.serving.scheduler import SchedulerConfig
 from repro.serving.workload import WorkloadConfig, generate
@@ -141,41 +131,6 @@ def _admission_compare(cfg, params, kb, n_req):
              f"prefill_batches={stats.prefill_batches}")
         thr_by_label[label] = thr
     return thr_by_label
-
-
-def _churn_workload(kb, n_req):
-    """All-at-once arrivals with varied decode lengths: with one
-    admission per iteration the decode batch churns on most steps."""
-    wl = WorkloadConfig(num_requests=n_req, qpm=1e9, seed=9, k_chunks=3,
-                        max_new_tokens=8)
-    reqs = generate(kb, wl)
-    for i, r in enumerate(reqs):
-        r.max_new_tokens = 4 + (i * 5) % 13
-    return reqs
-
-
-def _churn_compare(cfg, params, kb, n_req, warm: bool = True):
-    """Incremental decode batch (in-place join/leave) vs full rebuild on
-    every membership change, same churny schedule. Returns the rebuild
-    counters per mode (the count-based CI gate)."""
-    sched = SchedulerConfig(max_batch_tokens=100_000, max_decode_batch=8,
-                            max_prefill_batch=1)
-    exkw = dict(strategy="all", use_focus=False)
-    rebuilds = {}
-    for label, incremental in (("rebuild", False), ("incremental", True)):
-        eng, stats, thr, lat, _ttft = _measure(
-            cfg, params, None, sched, exkw, kb, n_req, qpm=1e9,
-            warm_same=warm, workload_fn=lambda: _churn_workload(kb, n_req),
-            decode_bucket_b=8, seq_bucket=256,
-            incremental_decode=incremental)
-        c = eng.counters
-        emit(f"fig22_decode_churn_{label}", lat * 1e6,
-             f"throughput_rps={thr:.3f};mean_e2e_s={lat:.3f};"
-             f"decode_rebuilds={c.decode_rebuilds};"
-             f"joins={c.decode_joins};leaves={c.decode_leaves};"
-             f"rows_recycled={c.decode_rows_recycled}")
-        rebuilds[label] = c.decode_rebuilds
-    return rebuilds
 
 
 def _overlap_workload(kb, n_req, k=3, max_new=6):
@@ -309,61 +264,6 @@ def _preemption_compare(cfg, params, kb, n_req, starved_blocks=20):
     return out
 
 
-# ---- paged decode (PR 10 tentpole) ------------------------------------------
-def _paged_compare(cfg, params, kb, n_req):
-    """Arena-gather decode vs block-table-native paged decode on the
-    churny join/leave schedule: streamed tokens AND per-step decode
-    logits bit-equal, while the paged engine moves strictly fewer
-    decode gather bytes (zero — its only decode-side traffic is block
-    writes into the pool twin: prefill blocks copied device to device,
-    decode-opened blocks uploaded from the dirty log). Returns the
-    count-based gate numbers per mode and appends the trajectory to
-    ``results/BENCH_paged.json``."""
-    sched = SchedulerConfig(max_batch_tokens=100_000, max_decode_batch=4,
-                            max_prefill_batch=2)
-    out, tokens, traces = {}, {}, {}
-    for label, paged in (("arena", False), ("paged", True)):
-        eng = make_engine(cfg, params, None, strategy="all",
-                          use_focus=False, sched=sched, pool_blocks=512,
-                          decode_bucket_b=4, seq_bucket=512,
-                          trace_decode=True, paged_decode=paged)
-        reqs = _churn_workload(kb, n_req)
-        stats = eng.run(reqs)
-        done = [r for r in reqs if r.e2e_latency is not None]
-        lat = float(np.mean([r.e2e_latency for r in done])) if done \
-            else 0.0
-        c = eng.counters
-        tokens[label] = {r.rid: list(r.output_tokens) for r in reqs}
-        traces[label] = eng.decode_trace
-        emit(f"fig22_paged_{label}", lat * 1e6,
-             f"mean_e2e_s={lat:.3f};"
-             f"decode_gather_bytes={c.decode_gather_bytes};"
-             f"decode_join_copies={c.decode_join_copies};"
-             f"paged_block_syncs={c.paged_block_syncs};"
-             f"paged_sync_bytes={c.paged_sync_bytes};"
-             f"completed={stats.completed};failed={stats.failed}")
-        out[label] = dict(
-            decode_gather_bytes=c.decode_gather_bytes,
-            decode_join_copies=c.decode_join_copies,
-            paged_block_syncs=c.paged_block_syncs,
-            paged_sync_bytes=c.paged_sync_bytes,
-            completed=stats.completed, failed=stats.failed)
-    out["tokens_equal"] = tokens["arena"] == tokens["paged"]
-    out["logits_equal"] = (
-        len(traces["arena"]) == len(traces["paged"]) > 0 and all(
-            set(ta) == set(tp) and all(
-                np.array_equal(ta[rid], tp[rid]) for rid in ta)
-            for ta, tp in zip(traces["arena"], traces["paged"])))
-    _record_trajectory(
-        "BENCH_paged.json",
-        dict(n_req=n_req,
-             tokens_equal=out["tokens_equal"],
-             logits_equal=out["logits_equal"], **{
-                 f"{k}_{label}": v for label in ("arena", "paged")
-                 for k, v in out[label].items()}))
-    return out
-
-
 # ---- tensor-parallel sharded serving (PR 6 tentpole) ------------------------
 # The parent process has already initialized jax on one device, so the
 # 4-device comparison runs in a child with XLA_FLAGS set before the
@@ -476,7 +376,6 @@ def run(quick: bool = False):
                  f"mean_ttft_s={ttft:.3f};tokens_saved={saved:.2f}")
 
     _admission_compare(cfg, params, kb, n_req)
-    _churn_compare(cfg, params, kb, n_req)
     _shared_blocks_compare(cfg, params, kb, n_req)
     _preemption_compare(cfg, params, kb, n_req=6 if quick else 10)
     _sharded_compare()
@@ -491,9 +390,6 @@ def ci_smoke() -> int:
       ``CI_SMOKE_TOLERANCE * serial`` (wall-clock-derived, so shared CI
       runners add noise on top of the real ~1.5x effect; default tol
       1.0 is the strict local threshold).
-    * decode churn — the incremental decode batch must absorb
-      membership churn with far fewer full rebuilds than rebuild mode
-      (count-based: immune to runner timing noise).
     * shared blocks — zero-copy sharing must reserve strictly fewer
       blocks at admission than the copy path on an overlapping-chunk
       workload, with shared (refcount > 1) blocks actually observed
@@ -524,13 +420,6 @@ def ci_smoke() -> int:
       zero reserved blocks afterwards, zero FAILED, per-tenant TTFT /
       queue-wait p99 rollups present. Trajectory in
       ``results/BENCH_serve.json``.
-    * paged — arena vs block-table-native paged decode on the churny
-      schedule: streamed tokens and per-step decode logits bit-equal,
-      ``decode_gather_bytes`` strictly lower than arena (and exactly
-      zero, with zero join copies), dirty-block syncs observed — the
-      paged engine must be the same math reading KV in place from the
-      pool (all count-based). Trajectory in
-      ``results/BENCH_paged.json``.
     * frontier — the quality-vs-recompute frontier on the
       reordered-context workload
       (``quality_vs_recompute.frontier_compare``): some blend
@@ -547,11 +436,6 @@ def ci_smoke() -> int:
 
     thr = _admission_compare(cfg, params, kb, n_req=8)
     ok_adm = thr["packed"] >= tol * thr["serial"]
-
-    rebuilds = _churn_compare(cfg, params, kb, n_req=8, warm=False)
-    # "<<": rebuild mode regathers on (almost) every membership change,
-    # the incremental batch only when the bucketed shape must grow
-    ok_churn = rebuilds["incremental"] * 4 <= rebuilds["rebuild"]
 
     shb = _shared_blocks_compare(cfg, params, kb, n_req=8)
     ok_shared = (
@@ -630,19 +514,6 @@ def ci_smoke() -> int:
     # (sv["ok"]; trajectory in results/BENCH_serve.json)
     sv = serve_gate()
 
-    pg = _paged_compare(cfg, params, kb, n_req=6)
-    # bit-equality at strictly fewer moved bytes, all count-based: the
-    # paged engine reads KV in place from the pool, so the per-step
-    # gather traffic of the arena path must vanish outright
-    ok_paged = (
-        pg["tokens_equal"] and pg["logits_equal"]
-        and pg["arena"]["failed"] == 0 and pg["paged"]["failed"] == 0
-        and pg["paged"]["decode_gather_bytes"]
-        < pg["arena"]["decode_gather_bytes"]
-        and pg["paged"]["decode_gather_bytes"] == 0
-        and pg["paged"]["decode_join_copies"] == 0
-        and pg["paged"]["paged_block_syncs"] > 0)
-
     sh = _sharded_compare()
     # bit-equality + strictly-fewer-per-device-work, all count-based:
     # the sharded engine must be a pure repartitioning of the same math
@@ -659,8 +530,6 @@ def ci_smoke() -> int:
     gates = {
         "admission": dict(ok=ok_adm, tolerance=tol, **{
             f"throughput_rps_{k}": v for k, v in thr.items()}),
-        "decode_churn": dict(ok=ok_churn, **{
-            f"rebuilds_{k}": v for k, v in rebuilds.items()}),
         "shared_blocks": dict(ok=ok_shared, copy=shb["copy"],
                               zerocopy=shb["zerocopy"]),
         "preemption": dict(ok=ok_pre, off=pre["off"], on=pre["on"],
@@ -672,9 +541,6 @@ def ci_smoke() -> int:
                         logits_equal=sh["logits_equal"],
                         onedev=sh["onedev"], fourdev=sh["fourdev"]),
         "serve": sv,
-        "paged": dict(ok=ok_paged, tokens_equal=pg["tokens_equal"],
-                      logits_equal=pg["logits_equal"],
-                      arena=pg["arena"], paged=pg["paged"]),
         "frontier": dict(ok=fr["ok"], eps=fr["eps"],
                          anchor=fr["anchor"], blend_win=fr["blend_win"]),
         "quant": dict(ok=ok_quant, capacity_fp32=evq["fp32"],
@@ -703,15 +569,13 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--ci-smoke", action="store_true",
                     help="run the CI perf gates (admission throughput, "
-                         "decode-churn rebuild counts, copy-vs-zerocopy "
-                         "reserved blocks, preemption counts + logits "
+                         "copy-vs-zerocopy reserved blocks, preemption counts + logits "
                          "bit-equality, eviction tier misses, preload "
                          "overlap, sharded bit-equality + per-device "
                          "FLOPs/bytes, quantized-tier capacity + "
                          "quality delta, online-serve HTTP streaming "
                          "bit-equality + mid-decode cancel, blend-vs-"
-                         "cachecraft recompute frontier, paged-decode "
-                         "bit-equality at zero gather bytes); writes "
+                         "cachecraft recompute frontier); writes "
                          "results/fig22_ci_smoke.json; exit 1 on any "
                          "gate failure")
     args = ap.parse_args()

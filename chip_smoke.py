@@ -23,9 +23,9 @@ Phases (one chip):
   printed as smoke timings, not benchmark results.
 * reference — the full-recompute strategy's first-token logits against
   a plain cacheless forward at ``highest`` matmul precision.
-* kernels — the same requests with the Pallas chunk-attention + decode
-  kernels, and with paged decode on the Pallas paged kernel, against the
-  dense run; the compiled engine steps must contain ``tpu_custom_call``.
+* kernels — the same requests with the Pallas chunk-attention prefill
+  and the Pallas paged decode kernel, against the dense run; the
+  compiled engine steps must contain ``tpu_custom_call``.
 * server — ``CacheCraftServer`` streams 3 requests over HTTP; the
   streams must equal an offline ``Engine.run`` of the same requests.
 
@@ -255,13 +255,10 @@ class StepRecorder:
 
 def record_steps(eng):
     """Install recorders on the engine's prefill window and decode step."""
-    rec = {"prefill window": StepRecorder(eng.executor._window)}
+    rec = {"prefill window": StepRecorder(eng.executor._window),
+           "paged decode step": StepRecorder(eng._paged_fn)}
     eng.executor._window = rec["prefill window"]
-    if eng.paged_decode:
-        rec["paged decode step"] = eng._paged_fn = StepRecorder(
-            eng._paged_fn)
-    else:
-        rec["decode step"] = eng._decode_fn = StepRecorder(eng._decode_fn)
+    eng._paged_fn = rec["paged decode step"]
     return rec
 
 
@@ -363,24 +360,22 @@ def phase_reference(cfg, params, sizes: Sizes, seed: int):
 
 
 def phase_kernels(cfg, params, sizes: Sizes, seed: int, dense):
-    """The Pallas routes on the same requests, against the dense run.
-    Both use full recompute, so their plans match the dense run row for
-    row (under cachecraft the kernel path scores chunks without
-    key-side mass, and would legitimately pick other recompute rows)."""
-    for name, over in (("kernel", dict(attn_impl="kernel")),
-                       ("paged_kernel", dict(attn_impl="paged_kernel",
-                                             paged_decode=True))):
-        eng = build(cfg, params, sizes, strategy="all", **over)
-        rec = record_steps(eng)
-        reqs = make_requests(cfg, sizes, seed)
-        stats, _ = run_workload(eng, reqs)
-        assert_served(stats, reqs, sizes.requests)
-        compare_runs(f"{name} vs dense", dense,
-                     (reqs, token_logits(eng, reqs)))
-        assert_kernels_compiled(name, rec)
-        retire(eng)
-        del eng, rec
-        gc.collect()
+    """The Pallas route (chunk-attention prefill, paged decode kernel)
+    on the same requests, against the dense run. Full recompute, so its
+    plans match the dense run row for row (under cachecraft the kernel
+    path scores chunks without key-side mass, and would legitimately
+    pick other recompute rows)."""
+    name = "paged_kernel"
+    eng = build(cfg, params, sizes, strategy="all", attn_impl=name)
+    rec = record_steps(eng)
+    reqs = make_requests(cfg, sizes, seed)
+    stats, _ = run_workload(eng, reqs)
+    assert_served(stats, reqs, sizes.requests)
+    compare_runs(f"{name} vs dense", dense, (reqs, token_logits(eng, reqs)))
+    assert_kernels_compiled(name, rec)
+    retire(eng)
+    del eng, rec
+    gc.collect()
     check_rope_kernel(cfg)
 
 
@@ -444,8 +439,9 @@ def phase_server(cfg, params, sizes: Sizes, seed: int):
 
 def phase_sharded(cfg, params, sizes: Sizes, seed: int, n_dev: int):
     """Head-sharded serving on an ``n_dev``-chip mesh against the same
-    requests on one device: the ``sharded`` backend, then paged decode
-    on the Pallas paged kernel run per shard."""
+    requests on one device: ``sharded`` prefill with the paged gather
+    decode under the mesh, then the Pallas route, whose paged decode
+    kernel runs per shard."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
     from repro.launch.mesh import make_serving_mesh
@@ -470,7 +466,7 @@ def phase_sharded(cfg, params, sizes: Sizes, seed: int, n_dev: int):
     del leaves
     for name, over in (("sharded", {}),
                        ("sharded paged_kernel",
-                        dict(attn_impl="paged_kernel", paged_decode=True))):
+                        dict(attn_impl="paged_kernel"))):
         eng = build(cfg, params, sizes, strategy="all", mesh=mesh, **over)
         check(eng.kv_shards == n_dev, f"kv_shards {eng.kv_shards}")
         reqs = make_requests(cfg, sizes, seed)

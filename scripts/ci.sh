@@ -16,7 +16,6 @@
 # Perf smoke (ROADMAP): with CI_PERF_SMOKE=1 (or --perf-smoke), a
 # quick-mode run of benchmarks/throughput_latency.py gates on
 #   * packed admission >= CI_SMOKE_TOLERANCE * serial throughput,
-#   * incremental decode-churn rebuild count << rebuild-mode count,
 #   * zero-copy sharing reserving strictly fewer blocks than the copy
 #     path on an overlapping-chunk workload,
 #   * reservation-aware preemption on a pool-starved workload:
@@ -52,19 +51,12 @@
 #     (CacheBlend fusion — top KV-deviation tokens anywhere in the
 #     chunk) must reach ROUGE-L within eps of the cachecraft anchor
 #     point at a STRICTLY lower recompute-token count (count-based),
-#   * paged decode: block-table-native decode reading KV in place from
-#     the pool vs the arena-gather path on a churny join/leave
-#     schedule — streamed tokens and per-step decode logits bit-equal
-#     while decode_gather_bytes is strictly lower than arena (exactly
-#     zero, with zero join copies and dirty-block syncs observed;
-#     count-based),
 # and writes results/fig22_ci_smoke.json for the CI artifact upload
 # (plus the preemption trajectory in results/BENCH_preemption.json,
 # the sharded trajectory in results/BENCH_sharded.json, the quant
 # trajectory in results/BENCH_quant.json, the serve trajectory in
-# results/BENCH_serve.json, the frontier trajectory in
-# results/BENCH_frontier.json, and the paged trajectory in
-# results/BENCH_paged.json).
+# results/BENCH_serve.json, and the frontier trajectory in
+# results/BENCH_frontier.json).
 # --smoke-only skips the pytest suite for fast local iteration on the
 # perf gates.
 set -euo pipefail
@@ -112,14 +104,13 @@ if [[ "$smoke_only" == "0" ]]; then
 fi
 
 if [[ "$status" == "0" && "$perf_smoke" == "1" ]]; then
-    echo "CI: perf smoke (admission throughput + decode-churn counts" \
+    echo "CI: perf smoke (admission throughput" \
          "+ copy-vs-zerocopy shared-block gate + preemption gate" \
          "+ eviction tier-miss gate + layerwise-preload gate" \
          "+ sharded bit-equality/FLOPs gate" \
          "+ quantized-tier capacity/quality gate" \
          "+ online-serve HTTP streaming/cancel gate" \
-         "+ blend-vs-cachecraft recompute-frontier gate" \
-         "+ paged-decode bit-equality/zero-gather gate)"
+         "+ blend-vs-cachecraft recompute-frontier gate)"
     python -m benchmarks.throughput_latency --ci-smoke || status=$?
     echo "CI perf smoke exit status: $status"
 fi

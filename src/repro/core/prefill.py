@@ -87,27 +87,6 @@ def _window_fn(cfg):
 
 
 @functools.lru_cache(maxsize=None)
-def decode_fn(cfg, attn_impl="auto"):
-    """Shared jitted one-token decode (engine + benches). ``slots`` (the
-    cache write index) is separate from ``positions`` (the RoPE/causality
-    position): paged storage appends at the next free slot while the
-    token's logical position keeps counting real tokens.
-
-    Row masking (incremental decode batch): a batch row with no live
-    request passes ``positions[i] == -1`` and ``slots[i] == -1`` — the
-    KV write for that row is dropped, the position mask zeroes all of
-    its attention, and its logits are garbage-but-finite and unread.
-    The engine recycles such rows in place on the next join instead of
-    rebuilding the whole (B, S) batch."""
-    @jax.jit
-    def decode_step(params, tokens, positions, cache, slots=None):
-        out = M.decode_step(cfg, params, tokens, positions, cache,
-                            decode_slot=slots, attn_impl=attn_impl)
-        return out.logits, out.cache
-    return decode_step
-
-
-@functools.lru_cache(maxsize=None)
 def paged_decode_fn(cfg, attn_impl="paged", block_size=0):
     """Jitted one-token decode over the POOL-TWIN cache (paged decode:
     leaves ``{"kp": [NBf,Hkv,D], "vp", "ppos": [NBf]}`` shared by every
@@ -118,8 +97,10 @@ def paged_decode_fn(cfg, attn_impl="paged", block_size=0):
     row). ``rows [B, S]`` are the compact slot-index rows for the
     *existing* tokens; the appended token's slot is spliced in here at
     column ``positions`` (its logical index) so attention sees it the
-    same step it is written — exactly like the arena path. The cache is
-    donated: the twin is large (the whole pool) and must not double.
+    same step it is written. A masked row (``positions`` and ``slots``
+    -1) writes nothing and attends to nothing; its outputs are finite
+    and unread. The cache is donated: the twin is large (the whole
+    pool) and must not double.
 
     Returns ``((logits, next_tokens, new_kv), cache)``: ``next_tokens
     [B] int32`` is the argmax over the first ``vocab_size`` logits (the

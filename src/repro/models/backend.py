@@ -28,7 +28,7 @@ bit-equality harness:
   the numbers.
 * **decode slots** — decode queries carry position -1 on masked batch
   rows (no live request); every backend must yield inert (zero) rows
-  there, so incremental decode joins/leaves cannot perturb live rows.
+  there, so decode-batch joins/leaves cannot perturb live rows.
 
 ``row_mass [B,Tq,C]`` / ``key_mass [B,S]`` are the Cache-Craft
 attention statistics (None when not collected; the Pallas kernel path
@@ -82,8 +82,8 @@ by every request — and the per-request view arrives through ``ctx``:
 ``paged`` dereferences the slot rows with a device-side gather and
 delegates to the dense (or mesh-installed ``sharded``) oracle — the
 gathered operand reproduces ``pool.gather(compact=True)``'s layout
-element-for-element, so logits stay BIT-identical to the arena path
-while the host-side arena copy (``decode_gather_bytes``) disappears.
+element-for-element, so its logits are BIT-identical to the dense
+backend's over that gathered copy, which stays the reference.
 ``paged_kernel`` skips even that gather: the kernel walks the block
 rows in place; head-sharded pools route each shard's ``kv_shards``
 view through the same kernel under ``compat.shard_map``. Both yield
@@ -357,8 +357,8 @@ def _impl_paged(ctx, window, packed, q, k_all, v_all, kv_pos):
     slot-index rows with one device-side gather and hand the result to
     the dense / sharded oracle. The gathered operand is
     ``pool.gather(compact=True)`` element-for-element (zeros + pos -1
-    in padding), so logits are bit-identical to the arena path — while
-    no host-side arena copy exists to build, rebuild, or join."""
+    in padding), so logits are bit-identical to the dense backend over
+    that gathered copy."""
     if ctx.paged_rows is None or k_all.ndim != 3:
         # not a pool-twin cache (e.g. unit tests driving the backend
         # with dense operands): the dense oracle is the fallback

@@ -350,10 +350,9 @@ def _self_attention(ctx: Ctx, kind: str, p, x, state):
                                              mode="drop")
         new_state = {"kp": k_all, "vp": v_all, "ppos": kv_pos}
     elif ctx.mode == "decode":
-        # Masked batch rows (incremental decode batch: no live request in
-        # the row) carry slot = -1 and position = -1: the KV write drops
-        # entirely and the row's query position masks all attention, so
-        # a dead row is inert until a join overwrites it.
+        # Masked batch rows (no live request in the row) carry slot = -1
+        # and position = -1: the KV write drops entirely and the row's
+        # query position masks all attention, so a dead row is inert.
         slot = ctx.decode_slot[:, None]
         if kind == "local":
             slot = jnp.where(slot >= 0, slot % state["k"].shape[1], slot)

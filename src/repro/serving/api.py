@@ -91,15 +91,11 @@ class EngineSpec:
     decode_bucket_b: int = 4
     seq_bucket: int = 64
     time_scale: float = 1.0
-    incremental_decode: bool = True
     share_chunk_kv: bool = True
     trace_decode: bool = False
-    # paged decode: block-table-native attention reads KV in place from
-    # a device twin of the pool (models/backend.py "Paged attend
-    # contract"); joins/leaves become row-map updates. ``attn_impl``
-    # may name "paged_kernel" to route the Pallas paged kernel instead
-    # of the gather-free reference backend
-    paged_decode: bool = False
+    # paged decode is the engine's only decode path; the key is accepted
+    # (True only) for configuration files that still state it
+    paged_decode: bool = True
     # chunk store (None -> no store, i.e. pure recompute serving)
     store: Optional[StoreSpec] = field(default_factory=StoreSpec)
 
@@ -107,6 +103,10 @@ class EngineSpec:
         """Fail fast with the offending field named. Returns self so
         call sites can chain ``EngineSpec(...).validate()``."""
         get_strategy(self.strategy)  # unknown -> ValueError with the name
+        if not self.paged_decode:
+            raise ValueError(
+                f"EngineSpec.paged_decode={self.paged_decode!r}: paged "
+                "decode is the only decode path")
         if self.attn_impl is not None:
             from repro.models.backend import BACKENDS
             if self.attn_impl not in BACKENDS and \
@@ -170,7 +170,6 @@ class EngineSpec:
             force_recompute_fraction=get("recompute", None),
             layerwise_load=get("layerwise_load", False),
             attn_impl=get("attn_impl", None),
-            paged_decode=get("paged_decode", False),
             pool_blocks=get("pool_blocks", cls.pool_blocks),
             sched=SchedulerConfig(
                 max_batch_tokens=get("max_batch_tokens", 8192),
@@ -256,8 +255,6 @@ def build_engine(spec: EngineSpec, *, cfg=None, params=None,
         store_new_chunks=spec.store_new_chunks,
         fix_rpe=spec.fix_rpe, fix_causality=spec.fix_causality,
         time_scale=spec.time_scale,
-        incremental_decode=spec.incremental_decode,
         share_chunk_kv=spec.share_chunk_kv,
         trace_decode=spec.trace_decode,
-        attn_impl=spec.attn_impl, paged_decode=spec.paged_decode,
-        mesh=spec.mesh)
+        attn_impl=spec.attn_impl, mesh=spec.mesh)

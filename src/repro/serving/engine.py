@@ -15,13 +15,14 @@ commit (success) or cancel (requeue/failure) it — so a request can
 never burn its share of the packed prefill pass and then fail
 ``write_prefill`` (``counters.burn_requeues`` stays 0).
 
-Incremental decode batch (row-masking scheme): the jitted decode cache
-is a bucketed (B, S) arena with a request-per-row map. Joins write the
-new request's gathered KV into a free row in place; leaves mask the row
-(cache position row set to -1, per-step query position/slot -1, see
-``core.prefill.decode_fn``) and recycle it for the next join. A full
-gather rebuild happens only when the bucketed (B, S) shape must grow,
-cutting per-iteration overhead under churny workloads.
+Decode batch (paged, row-map scheme): each decode step reads K/V in
+place from a device twin of the pool's block arenas, through per-request
+compact slot-index rows (``KVPool.table_slot_index``). The batch is a
+bucketed (B, S) index tensor with a request-per-row map: a join takes a
+free row, a leave empties it (an empty row passes position and slot -1,
+so it is inert) and the next join recycles it. No KV is copied for
+either. A rebuild only re-buckets (B, S) and resets the row map, when
+the shape must grow.
 
 Zero-copy chunk sharing (``share_chunk_kv``, on by default with a
 store): instead of copying every hit chunk's KV into private pool
@@ -92,11 +93,10 @@ batch-replay ``run`` and the server's live loop share one
 from __future__ import annotations
 
 import dataclasses
-import functools
 import threading
 import time
 import warnings
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -105,10 +105,10 @@ import numpy as np
 
 from repro.core.chunkstore import ChunkStore, prompt_hashes
 from repro.core.prefill import CacheCraftExecutor, inject_chunk_kv, \
-    pack_cache, prefill_len, zero_prefill_kv
+    pack_paged_cache, paged_decode_fn, paged_sync_fn, paged_twin_write_fn, \
+    prefill_len, zero_prefill_kv
 from repro.core.preload import preload_depth
 from repro.core.tiers import PrefetchTicket
-from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.serving.kvpool import KVPool
 from repro.serving.metrics import ServingCounters
@@ -117,8 +117,16 @@ from repro.serving.scheduler import Scheduler, SchedulerConfig
 from repro.tracing import span, to_host
 
 
-# prefill-window backend of each decode-only (paged) route
-_PREFILL_IMPL = {"paged": "dense", "paged_kernel": "kernel"}
+# attn_impl -> (prefill-window backend, paged decode backend). Any other
+# name, and None, leaves the prefill to the executor (None: its default)
+# and decodes through the ``paged`` gather route. Under a serving mesh
+# the prefill windows run ``sharded``; the ``paged`` gather then hands
+# its operands to ``sharded`` too, and ``paged_kernel`` runs per shard.
+_ROUTES = {
+    "kernel": ("kernel", "paged_kernel"),
+    "paged_kernel": ("kernel", "paged_kernel"),
+    "paged": ("dense", "paged"),
+}
 
 
 def _bucket(n: int, b: int) -> int:
@@ -130,64 +138,6 @@ def _sync_bucket(n: int) -> int:
     multiples of 8. The padding is uploaded too, so a lone
     decode-opened block moves one block, not eight."""
     return 1 << (n - 1).bit_length() if n < 8 else _bucket(n, 8)
-
-
-@functools.lru_cache(maxsize=None)
-def _join_row_fn(cfg):
-    """Jitted in-place decode-batch join: write one request's gathered
-    KV [L, S, Hkv, D] (+ pos [S]) into batch row ``row`` of the decode
-    cache. One fused call (cache donated, so XLA can alias the buffers
-    where the backend supports it) instead of 3 * (P + n_tail) separate
-    whole-cache copies."""
-    P, G = len(cfg.pattern), cfg.n_groups
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def decode_join_row(cache, row, k, v, pos):
-        out = {"groups": [], "tail": []}
-        if G:
-            kg = k[:G * P].reshape((G, P) + k.shape[1:])
-            vg = v[:G * P].reshape((G, P) + v.shape[1:])
-            for p in range(P):
-                c = cache["groups"][p]
-                out["groups"].append({
-                    "k": c["k"].at[:, row].set(kg[:, p]),
-                    "v": c["v"].at[:, row].set(vg[:, p]),
-                    "pos": c["pos"].at[:, row].set(pos),
-                })
-        for i in range(cfg.n_tail):
-            t = cache["tail"][i]
-            out["tail"].append({
-                "k": t["k"].at[row].set(k[G * P + i]),
-                "v": t["v"].at[row].set(v[G * P + i]),
-                "pos": t["pos"].at[row].set(pos),
-            })
-        return out
-    return decode_join_row
-
-
-@functools.lru_cache(maxsize=None)
-def _leave_row_fn(cfg):
-    """Jitted in-place decode-batch leave: mask batch row ``row`` by
-    setting its position row to -1 (KV left in place — the position
-    mask makes the row inert, and the next join overwrites it)."""
-    G = cfg.n_groups
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def decode_leave_row(cache, row):
-        out = {"groups": [], "tail": []}
-        if G:
-            for c in cache["groups"]:
-                out["groups"].append({
-                    "k": c["k"], "v": c["v"],
-                    "pos": c["pos"].at[:, row].set(-1),
-                })
-        for t in cache["tail"]:
-            out["tail"].append({
-                "k": t["k"], "v": t["v"],
-                "pos": t["pos"].at[row].set(-1),
-            })
-        return out
-    return decode_leave_row
 
 
 @dataclass
@@ -233,21 +183,20 @@ class Engine:
                  fix_rpe: bool = True, fix_causality: bool = True,
                  executor_kwargs: Optional[dict] = None,
                  time_scale: float = 1.0,
-                 incremental_decode: bool = True,
                  share_chunk_kv: bool = True,
                  trace_decode: bool = False,
                  attn_impl: Optional[str] = None,
-                 paged_decode: bool = False,
                  mesh=None):
         self.cfg = cfg
         self.store = store
-        # attention backend selection (models.backend.BACKENDS). None
-        # keeps the legacy split: "dense" prefill windows, "auto"
-        # decode. A serving mesh forces the "sharded" backend and a
-        # matching head-sharded pool layout; the mesh must be installed
-        # before the first trace of any jit root that runs under it.
-        # Params are replicated onto the mesh, so every shard reads its
-        # own copy instead of pulling from wherever init left them.
+        # attention backends (models.backend.BACKENDS), from ``_ROUTES``.
+        # A serving mesh brings a matching head-sharded pool layout; the
+        # mesh must be installed before the first trace of any jit root
+        # that runs under it. Params are replicated onto the mesh, so
+        # every shard reads its own copy instead of pulling from
+        # wherever init left them.
+        prefill_impl, decode_impl = _ROUTES.get(attn_impl,
+                                                (attn_impl, "paged"))
         self.mesh = mesh
         kv_shards = 1
         if mesh is not None:
@@ -258,12 +207,8 @@ class Engine:
             AB.set_serving_mesh(mesh)
             params = jax.device_put(
                 params, NamedSharding(mesh, PartitionSpec()))
-            # the paged route keeps its kernel under the mesh (the
-            # backend runs it per shard); everything else goes sharded
-            if not (paged_decode and attn_impl == "paged_kernel"):
-                attn_impl = "sharded"
+            prefill_impl = "sharded"
         self.params = params
-        self.attn_impl = attn_impl
         self.kv_shards = kv_shards
         # typed executor construction (serving.api.EngineSpec is the
         # front door). ``executor_kwargs`` is a deprecated alias kept
@@ -282,11 +227,8 @@ class Engine:
                 "(or the Engine keyword arguments it forwards)",
                 DeprecationWarning, stacklevel=2)
             ek.update(executor_kwargs)
-        if attn_impl is not None:
-            # the paged routes only change decode: their prefill windows
-            # run the backend each reduces to (sharded under a mesh)
-            ek.setdefault("attn_impl", "sharded" if mesh is not None
-                          else _PREFILL_IMPL.get(attn_impl, attn_impl))
+        if prefill_impl is not None:
+            ek.setdefault("attn_impl", prefill_impl)
         self.counters = ServingCounters()
         self.executor = CacheCraftExecutor(cfg, params, store,
                                            counters=self.counters, **ek)
@@ -310,10 +252,8 @@ class Engine:
         self.decode_bucket_b = decode_bucket_b
         self.seq_bucket = seq_bucket
         self.time_scale = time_scale
-        self.incremental_decode = incremental_decode
         self.clock = 0.0
         self.decoding: List[Request] = []
-        self._dcache = None
         self._dshape = None
         self._rows: List[Optional[Request]] = []   # batch row -> request
         self._masked_rows: set = set()             # rows freed by a leave
@@ -334,12 +274,10 @@ class Engine:
         self._token_events: List[Tuple[int, int]] = []
         self._events_lock = threading.Lock()
         self._cancel_pending: set = set()
-        from repro.core.prefill import decode_fn
-        self._decode_fn = decode_fn(cfg, self.attn_impl or "auto")
         # paged decode (block-table-native attention): the decode pass
         # reads K/V in place from a device twin of the pool's block
         # arenas, indexed per request by compact slot rows — joins and
-        # leaves become row-map updates, rebuilds only re-bucket the
+        # leaves are row-map updates, rebuilds only re-bucket the
         # index tensor, and the new token's KV is scattered into its
         # pre-opened pool slot inside the jitted pass. Blocks a prefill
         # fills from its own result (private segments, copy-mode
@@ -349,20 +287,12 @@ class Engine:
         # fixup rows, decode-opened append blocks, prefills before the
         # twin's first pack — stays in the pool's dirty-block log and is
         # uploaded before the next step (paged_block_syncs /
-        # paged_sync_bytes), while the arena path's per-request copies
-        # land in decode_gather_bytes / decode_join_copies — ~0 here.
-        self.paged_decode = bool(paged_decode)
+        # paged_sync_bytes).
         self._pcache = None
-        self._paged_kernel = bool(paged_decode) and \
-            self.attn_impl in ("paged_kernel",)
-        if paged_decode:
-            from repro.core.prefill import paged_decode_fn, paged_sync_fn, \
-                paged_twin_write_fn
-            impl = self.attn_impl \
-                if self.attn_impl in ("paged", "paged_kernel") else "paged"
-            self._paged_fn = paged_decode_fn(cfg, impl, block_size)
-            self._psync = paged_sync_fn(cfg)
-            self._twin_write = paged_twin_write_fn(cfg)
+        self._paged_kernel = decode_impl == "paged_kernel"
+        self._paged_fn = paged_decode_fn(cfg, decode_impl, block_size)
+        self._psync = paged_sync_fn(cfg)
+        self._twin_write = paged_twin_write_fn(cfg)
 
     # ---- submission ---------------------------------------------------------
     def submit(self, req: Request):
@@ -465,7 +395,7 @@ class Engine:
                     self._decode_leave(row)
                 else:
                     # admitted while a rebuild was pending: membership
-                    # changed under the stale cache (same edge as
+                    # changed under the stale row map (same edge as
                     # ``_preempt``)
                     self._needs_rebuild = True
                 self._finish_cancel(r)
@@ -490,7 +420,8 @@ class Engine:
                     self._run_prefills(reqs)
                 worked = True
             if self.decoding:
-                self._run_decode_step()
+                with span("cc.decode", b=len(self.decoding)):
+                    self._run_decode_step()
                 worked = True
             return worked
 
@@ -978,7 +909,7 @@ class Engine:
             self._decode_leave(row)
         else:
             # admitted while a rebuild was pending: never entered the
-            # row map, so membership just changed under the stale cache
+            # row map, so membership just changed under the stale row map
             self._needs_rebuild = True
         recovered = self._teardown(req)
         req.reserve_full = False
@@ -988,71 +919,33 @@ class Engine:
 
     # ---- decode batch -------------------------------------------------------
     def _row_capacity(self, req: Request) -> int:
-        """Arena sequence slots this request may touch while decoding
-        (the arena holds the compact logical view, so capacity follows
-        ``total_len``, not the block-aligned table length)."""
+        """Columns of the (B, S) slot-index rows this request may fill
+        while decoding: one per logical token (the rows are the compact
+        view, so capacity follows ``total_len``, not the block-aligned
+        table length)."""
         return req.total_len + req.max_new_tokens + 1
 
     def _rebuild_decode_batch(self):
+        """Re-bucket (B, S) and reset the row map: the slot rows are
+        exported from the block tables every step (int32 indices, not
+        KV), so a membership change that grows the shape costs nothing
+        else."""
         B = _bucket(len(self.decoding), self.decode_bucket_b)
         max_len = max(self._row_capacity(r) for r in self.decoding)
-        S = _bucket(max_len, self.seq_bucket)
-        if self.paged_decode:
-            # paged rebuild = re-bucket the index tensor: the slot rows
-            # are re-exported from the block tables every step anyway
-            # (they are [B, S] int32, not KV), so a membership change
-            # that grows (B, S) costs a row-map reset and nothing else —
-            # no gather, no transfer (decode_gather_bytes unchanged)
-            self._dshape = (B, S)
-            self._rows = list(self.decoding) + \
-                [None] * (B - len(self.decoding))
-            self._masked_rows = set()
-            self._needs_rebuild = False
-            self.counters.decode_rebuilds += 1
-            return
-        L = self.cfg.num_layers
-        hkv, dh = self.cfg.num_kv_heads, self.cfg.head_dim_
-        k = np.zeros((L, B, S, hkv, dh), np.float32)
-        v = np.zeros_like(k)
-        pos = np.full((B, S), -1, np.int32)
-        for i, r in enumerate(self.decoding):
-            kk, vv, pp = self.pool.gather(r.table, S, compact=True)
-            self.counters.decode_gather_bytes += kk.nbytes + vv.nbytes
-            k[:, i], v[:, i], pos[i] = kk, vv, pp
-        # to model cache format (batched pack)
-        P, G = len(self.cfg.pattern), self.cfg.n_groups
-        groups = []
-        if G:
-            kg = k[:G * P].reshape(G, P, B, S, hkv, dh)
-            vg = v[:G * P].reshape(G, P, B, S, hkv, dh)
-            for p in range(P):
-                groups.append({"k": jnp.asarray(kg[:, p]),
-                               "v": jnp.asarray(vg[:, p]),
-                               "pos": jnp.broadcast_to(
-                                   jnp.asarray(pos), (G, B, S))})
-        tail = [{"k": jnp.asarray(k[G * P + i]),
-                 "v": jnp.asarray(v[G * P + i]),
-                 "pos": jnp.asarray(pos)} for i in range(self.cfg.n_tail)]
-        self._dcache = {"groups": groups, "tail": tail}
-        self._dshape = (B, S)
+        self._dshape = (B, _bucket(max_len, self.seq_bucket))
         self._rows = list(self.decoding) + [None] * (B - len(self.decoding))
         self._masked_rows = set()
         self._needs_rebuild = False
         self.counters.decode_rebuilds += 1
 
     def _decode_join_batch(self, reqs: Sequence[Request]):
-        """Join newly-decoding requests into the decode batch in place,
-        or fall back to a full rebuild (flag only — the rebuild itself
-        is lazy) when there is no cache yet, not enough free rows, or
-        the row arena is too short for any of them. The all-or-nothing
-        check runs before the first join so a rebuild-forcing member
-        does not waste the earlier members' gathers and transfers."""
+        """Join newly-decoding requests into free rows of the decode
+        batch, or fall back to a rebuild (flag only — the rebuild itself
+        is lazy) when there is no batch yet, not enough free rows, or
+        the rows are too short for any of them."""
         if not reqs:
             return
-        have_batch = self._dshape is not None if self.paged_decode \
-            else self._dcache is not None
-        if not self.incremental_decode or not have_batch or \
-                self._needs_rebuild:
+        if self._dshape is None or self._needs_rebuild:
             self._needs_rebuild = True
             return
         _B, S = self._dshape
@@ -1064,22 +957,10 @@ class Engine:
             self._decode_join(req)
 
     def _decode_join(self, req: Request):
-        """Write one newly-decoding request's gathered KV into a free
-        batch row in place (capacity pre-checked by
-        ``_decode_join_batch``)."""
-        _B, S = self._dshape
+        """Put one newly-decoding request in a free batch row (capacity
+        pre-checked by ``_decode_join_batch``); its slot rows are
+        exported at the next step."""
         row = self._rows.index(None)
-        if not self.paged_decode:
-            # arena join: the only path that copies KV to admit a
-            # request into the decode batch. Paged joins stop here —
-            # the request's slot rows are exported (int32 indices, not
-            # KV) at the next step
-            k, v, pos = self.pool.gather(req.table, S, compact=True)
-            self.counters.decode_gather_bytes += k.nbytes + v.nbytes
-            self.counters.decode_join_copies += 1
-            self._dcache = _join_row_fn(self.cfg)(
-                self._dcache, jnp.int32(row), jnp.asarray(k),
-                jnp.asarray(v), jnp.asarray(pos))
         self._rows[row] = req
         self.counters.decode_joins += 1
         if row in self._masked_rows:
@@ -1087,26 +968,12 @@ class Engine:
             self.counters.decode_rows_recycled += 1
 
     def _decode_leave(self, row: int):
-        """Mask a departing request's batch row: position row -> -1 kills
-        every key in the row's attention; the row is recycled by the
-        next join. In rebuild mode the whole batch is regathered
-        instead."""
+        """Empty a departing request's batch row: the departed table's
+        slots stop being referenced by any index row, and the row is
+        recycled by the next join."""
         self._rows[row] = None
-        if not self.incremental_decode:
-            self._needs_rebuild = True
+        if self._dshape is None or self._needs_rebuild:
             return
-        if self.paged_decode:
-            # paged leave: pure row-map update — the departed table's
-            # slots simply stop being referenced by any index row
-            if self._dshape is None or self._needs_rebuild:
-                return
-            self._masked_rows.add(row)
-            self.counters.decode_leaves += 1
-            return
-        if self._dcache is None or self._needs_rebuild:
-            return
-        self._dcache = _leave_row_fn(self.cfg)(self._dcache,
-                                               jnp.int32(row))
         self._masked_rows.add(row)
         self.counters.decode_leaves += 1
 
@@ -1147,7 +1014,7 @@ class Engine:
             kp[:, ids].nbytes + vp[:, ids].nbytes)
         self.pool.clear_dirty(ids)
 
-    def _run_decode_step_paged(self):
+    def _run_decode_step(self):
         """One decode iteration, block-table-native: attention reads
         K/V in place from the pool twin through per-request compact
         slot-index rows (``KVPool.table_slot_index``) — no per-request
@@ -1156,8 +1023,7 @@ class Engine:
 
         Per-step ordering: (1) pre-open every live row's append slot
         (``ensure_append_slot`` — the one step that can fail under pool
-        pressure, so the failure escalation the arena path applies
-        *after* the pass happens here *before* any compute is spent);
+        pressure, so a failure escalates *before* any compute is spent);
         (2) bring the device twin up to date (initial wholesale pack,
         then dirty-block scatters); (3) run the jitted pass, which
         splices each row's pre-opened slot into its index row and
@@ -1178,8 +1044,10 @@ class Engine:
                                                  reservation=r.reservation)
                 if s is None:
                     # zero-copy: CoW fixups may have drained the delta
-                    # reservation — escalate to a full reservation, same
-                    # as the arena path's post-step append failure
+                    # reservation write_rows drew on — escalate the retry
+                    # to a full reservation like the write-back burn path,
+                    # so the request cannot exhaust retries and FAIL where
+                    # the copy path would have served it
                     r.reserve_full = True
                     self.decoding.remove(r)
                     self._decode_leave(i)
@@ -1211,7 +1079,6 @@ class Engine:
                         brows[i] = self.pool.table_block_row(r.table, nbm)
         with span("cc.decode.sync"):
             if self._pcache is None:
-                from repro.core.prefill import pack_paged_cache
                 self._pcache = pack_paged_cache(self.cfg,
                                                 *self.pool.block_view())
                 self.pool.clear_dirty(self.pool.dirty_blocks())
@@ -1260,102 +1127,6 @@ class Engine:
                     self.pool.commit(r.reservation)
                     r.reservation = None
                     self.scheduler.on_terminal(r)
-
-    def _run_decode_step(self):
-        with span("cc.decode", b=len(self.decoding)):
-            if self.paged_decode:
-                self._run_decode_step_paged()
-            else:
-                self._run_decode_step_arena()
-
-    def _run_decode_step_arena(self):
-        with span("cc.decode.prepare"):
-            if self._dcache is None or self._needs_rebuild:
-                self._rebuild_decode_batch()
-            B, S = self._dshape
-            toks = np.zeros(B, np.int32)
-            poss = np.full(B, -1, np.int32)
-            slots = np.full(B, -1, np.int32)
-            for i, r in enumerate(self._rows):
-                if r is None:                  # masked row: inert (see
-                    continue                   # decode_fn row-masking)
-                toks[i] = r.output_tokens[-1]
-                poss[i] = r.total_len          # logical position (RoPE/causal)
-                slots[i] = r.total_len         # arena append slot (compact
-                #   logical view; the pool's block-aligned slot is private
-                #   to append_token below)
-        with span("cc.decode.run"):
-            t0 = time.perf_counter()
-            logits, self._dcache = self._decode_fn(
-                self.params, jnp.asarray(toks), jnp.asarray(poss),
-                self._dcache, jnp.asarray(slots))
-            logits = to_host(logits[:, 0], self.counters)
-            self.clock += (time.perf_counter() - t0) * self.time_scale
-            self.stats.decode_steps += 1
-            self._count_attn_flops(B, S)
-            if self.trace_decode:
-                self.decode_trace.append(
-                    {r.rid: logits[i].copy() for i, r in enumerate(self._rows)
-                     if r is not None})
-        with span("cc.decode.mirror"):
-            for i, r in enumerate(list(self._rows)):
-                if r is None:
-                    continue
-                nxt = int(np.argmax(logits[i, :self.cfg.vocab_size]))
-                # persist the newly written KV into the paged pool
-                ktok, vtok = self._extract_slot_kv(i, r.total_len)
-                if not self.pool.append_token(r.table, ktok, vtok,
-                                              r.total_len,
-                                              reservation=r.reservation):
-                    # zero-copy: CoW fixups may have drained the delta
-                    # reservation write_rows drew on — escalate the retry
-                    # to a full reservation like the write-back burn path,
-                    # so the request cannot exhaust retries and FAIL where
-                    # the copy path would have served it
-                    r.reserve_full = True
-                    self.decoding.remove(r)
-                    self._decode_leave(i)
-                    self._requeue(r)
-                    continue
-                r.output_tokens.append(nxt)
-                self._emit_token(r, nxt)
-                r.total_len += 1
-                if len(r.output_tokens) >= r.max_new_tokens:
-                    r.state = State.DONE
-                    r.t_done = self.clock
-                    self.stats.completed += 1
-                    self.decoding.remove(r)
-                    self._decode_leave(i)
-                    if self.trace_decode:
-                        pad = _bucket(max(r.table.length, 1), self.seq_bucket)
-                        self.final_kv[r.rid] = self.pool.gather(r.table, pad)
-                    self.pool.free_table(r.table)
-                    self._release_runs(r)
-                    self.pool.commit(r.reservation)
-                    r.reservation = None
-                    self.scheduler.on_terminal(r)
-
-    def _extract_slot_kv(self, batch_idx: int, slot: int):
-        cfg = self.cfg
-        P, G = len(cfg.pattern), cfg.n_groups
-        L = cfg.num_layers
-        hkv, dh = cfg.num_kv_heads, cfg.head_dim_
-        k = np.zeros((L, hkv, dh), np.float32)
-        v = np.zeros((L, hkv, dh), np.float32)
-        for p in range(P):
-            kk = to_host(self._dcache["groups"][p]["k"]
-                         [:, batch_idx, slot], self.counters)
-            vv = to_host(self._dcache["groups"][p]["v"]
-                         [:, batch_idx, slot], self.counters)
-            for g in range(G):
-                k[g * P + p] = kk[g]
-                v[g * P + p] = vv[g]
-        for i in range(cfg.n_tail):
-            k[G * P + i] = to_host(
-                self._dcache["tail"][i]["k"][batch_idx, slot], self.counters)
-            v[G * P + i] = to_host(
-                self._dcache["tail"][i]["v"][batch_idx, slot], self.counters)
-        return k, v
 
     # ---- workload driver ------------------------------------------------------
     def step_until_idle(self, *, max_iters: Optional[int] = None,

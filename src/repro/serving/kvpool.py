@@ -97,7 +97,7 @@ logical arena unchanged. The invariants:
 Paged decode (``block_view`` / ``table_slot_index`` / dirty log)
 ----------------------------------------------------------------
 The paged decode path reads K/V **in place** from the block arenas —
-no per-request gather, no dense row-arena copy. The pool exports:
+no per-request gather or copy. The pool exports:
 
 * ``block_view()`` — the raw ``(k, v, pos)`` arenas, zero-copy. Every
   pool write (prefill, recompute fixups, CoW clones, decode appends)
@@ -108,7 +108,8 @@ no per-request gather, no dense row-arena copy. The pool exports:
   (``block_id * block_size + offset``) holding the token at logical
   position ``i``; -1 pads. Indexing the flattened arenas with this row
   reproduces ``gather(..., compact=True)``'s exact operand layout, so
-  paged attention stays bit-identical to the arena path.
+  paged attention is bit-identical to dense attention over that
+  gathered copy, which stays the reference.
 * ``table_block_row(table, pad_to)`` — the block-id row (-1 padded)
   the paged Pallas kernel's scalar-prefetched index maps consume.
 * ``ensure_append_slot(table, reservation)`` — pre-opens the next
@@ -613,7 +614,7 @@ class KVPool:
         Indexing the flattened arenas with this row reproduces
         ``gather(table, pad_to, compact=True)`` element-for-element, so
         a paged attention pass that dereferences it sees the exact
-        operand layout of the arena path — the bit-identity seam."""
+        operand layout of that gathered copy — the bit-identity seam."""
         out = np.full(pad_to, -1, np.int32)
         if table.length == 0 or not table.blocks:
             return out

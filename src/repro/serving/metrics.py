@@ -1,6 +1,6 @@
 """Quality metrics (paper §5.1.3): ROUGE-L F1 and Jaccard similarity over
 token sequences, plus deviation measures used in Figs. 7/12/15, the
-serving-side counters (reservation protocol + incremental decode batch)
+serving-side counters (reservation protocol + paged decode batch)
 shared by the pool, the engine, and the Fig. 22 benches, and the
 per-tenant SLO rollups (``tenant_rollups``) the online server's
 ``/stats`` endpoint reports."""
@@ -73,22 +73,13 @@ class ServingCounters:
     #     sharded-smoke gate is timing-immune)
     attn_flops_device: int = 0           # per-device share of the above
     #     (total / kv_shards; strictly below total on a real mesh)
-    # --- incremental decode batch ---
-    decode_rebuilds: int = 0             # full (B, S) gather rebuilds
-    #     (paged mode: (B, S) re-buckets of the index tensor — no KV
-    #     is gathered, see decode_gather_bytes)
-    decode_joins: int = 0                # requests written into a free row
-    decode_leaves: int = 0               # rows masked (pos = -1) on exit
-    decode_rows_recycled: int = 0        # masked rows reused by a join
+    # --- decode batch (row map over the (B, S) slot-index rows) ---
+    decode_rebuilds: int = 0             # (B, S) re-buckets of the index
+    #     tensor (no KV is gathered)
+    decode_joins: int = 0                # requests put in a free row
+    decode_leaves: int = 0               # rows emptied on exit
+    decode_rows_recycled: int = 0        # emptied rows reused by a join
     # --- paged decode (block-table-native attention) ---
-    decode_gather_bytes: int = 0         # KV bytes copied out of the pool
-    #     to build/maintain the arena decode batch (rebuild gathers +
-    #     join gathers). The paged path reads KV in place through slot
-    #     index rows, so this stays ~0 there — the Fig. 22 paged lane
-    #     gates on it
-    decode_join_copies: int = 0          # joins that copied KV into a
-    #     batch row (arena in-place joins); paged joins are row-map
-    #     updates and count 0 here
     paged_block_syncs: int = 0           # dirty pool blocks uploaded
     #     host->device into the twin before a paged step: shared-run
     #     loads, CoW clones, recompute fixups, decode-opened append

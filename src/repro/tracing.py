@@ -18,14 +18,13 @@ owns the device, records spans. Every name starts with ``cc.``::
         cc.prefill.capture  store inserts
         cc.prefill.write_back  pool write-back, decode-batch joins
         cc.prefill.twin     prefill blocks copied into the pool twin,
-                            device to device (paged decode)
+                            device to device
       cc.decode             one decode step (b live rows)
         cc.decode.prepare   slot opening, rebuild, index and block rows
         cc.decode.sync      device twin upload (pack or dirty blocks)
-        cc.decode.run       the jitted step and its one read (paged:
-                            tokens and new KV; arena: the logits)
-        cc.decode.mirror    pool append, token emit, completion (arena:
-                            argmax and KV read-back too)
+        cc.decode.run       the jitted step and its one read (tokens
+                            and new KV)
+        cc.decode.mirror    pool append, token emit, completion
     cc.dispatch             the server's token fan-out
     cc.idle                 the server's blocking inbox wait
 """

@@ -30,7 +30,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_tiny
-from repro.core.prefill import CacheCraftExecutor, decode_fn, pack_cache
+from repro.core.prefill import CacheCraftExecutor, pack_cache
 from repro.models import backend as AB
 from repro.models import model as M
 
@@ -126,6 +126,16 @@ def test_packed_segment_isolation(world):
                               np.asarray(pert[1].logits_last))
 
 
+def _decode(cfg, impl):
+    """One jitted decode step on a contiguous cache through backend
+    ``impl``: (params, tokens, positions, cache, slots) -> logits."""
+    @jax.jit
+    def step(params, tokens, positions, cache, slots):
+        return M.decode_step(cfg, params, tokens, positions, cache,
+                             decode_slot=slots, attn_impl=impl).logits
+    return step
+
+
 # ---- kernel backend: cross-impl agreement -----------------------------------
 @KERNEL
 def test_kernel_matches_dense_prefill_and_decode(world, prefilled):
@@ -141,8 +151,8 @@ def test_kernel_matches_dense_prefill_and_decode(world, prefilled):
                          res_k.pos_layout)
     toks = np.array([tok], np.int32)
     poss = np.array([res_d.total_len - 1], np.int32)
-    lk, _ = decode_fn(cfg, "kernel")(params, toks, poss, cache_k, poss)
-    ld, _ = decode_fn(cfg, "dense")(params, toks, poss, cache_d, poss)
+    lk = _decode(cfg, "kernel")(params, toks, poss, cache_k, poss)
+    ld = _decode(cfg, "dense")(params, toks, poss, cache_d, poss)
     np.testing.assert_allclose(np.asarray(lk), np.asarray(ld),
                                rtol=2e-4, atol=2e-4)
 
@@ -160,17 +170,17 @@ def _tile2(cache):
 @pytest.mark.parametrize("impl",
                          ["dense", pytest.param("kernel", marks=KERNEL)])
 def test_decode_masked_row_inert(prefilled, impl):
-    """A batch row with positions == slots == -1 (incremental decode
-    batch hole) must not perturb the live row and must stay finite."""
+    """A batch row with positions == slots == -1 (a decode batch
+    hole) must not perturb the live row and must stay finite."""
     cfg, params, res, cache, tok = prefilled
-    fn = decode_fn(cfg, impl)
+    fn = _decode(cfg, impl)
     p = res.total_len - 1
     toks1 = np.array([tok], np.int32)
     pos1 = np.array([p], np.int32)
-    ref, _ = fn(params, toks1, pos1, cache, pos1)
+    ref = fn(params, toks1, pos1, cache, pos1)
     toks2 = np.array([tok, tok], np.int32)
     pos2 = np.array([p, -1], np.int32)
-    lg, _ = fn(params, toks2, pos2, _tile2(cache), pos2)
+    lg = fn(params, toks2, pos2, _tile2(cache), pos2)
     lg, ref = np.asarray(lg), np.asarray(ref)
     assert np.isfinite(lg).all()       # masked row: garbage but finite
     np.testing.assert_allclose(lg[0], ref[0], rtol=2e-4, atol=2e-4)

@@ -67,8 +67,7 @@ def test_kernel_phase_runs_interpreted_kernels(world, monkeypatch):
 
     monkeypatch.setattr(smoke, "assert_kernels_compiled", interpreted)
     smoke.phase_kernels(cfg, params, TINY, seed=0, dense=dense)
-    assert seen == {"kernel": ["prefill window", "decode step"],
-                    "paged_kernel": ["prefill window",
+    assert seen == {"paged_kernel": ["prefill window",
                                      "paged decode step"]}
 
 

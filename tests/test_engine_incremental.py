@@ -1,11 +1,12 @@
-"""Incremental decode batch vs full rebuild, and reserve-at-admission.
+"""The decode batch's row map, and reserve-at-admission.
 
-Tier-1 gates for the reservation + incremental-decode tentpole:
+Tier-1 gates for the reservation + decode-batch design:
 
-* a churny join/leave schedule stepped with the incremental decode
-  batch must produce per-step decode logits and final pool KV identical
-  to the always-rebuild path, while handling membership changes without
-  full rebuilds (asserted via the rebuild counter);
+* a churny join/leave schedule must produce, for every request, per-step
+  decode logits and final pool KV identical to the same requests
+  submitted one at a time on the same buckets, while handling
+  membership changes without rebuilds (asserted via the rebuild
+  counter);
 * under pool pressure with reservations on, no request may ever enter
   the packed compute pass and then fail ``write_prefill``
   (``burn_requeues == 0``);
@@ -48,64 +49,74 @@ def _churny_requests(kb):
     return reqs
 
 
-def _run(cfg, params, kb, incremental):
-    eng = build_engine(
+def _engine(cfg, params):
+    return build_engine(
         EngineSpec(strategy="all", use_focus=False,
                    pool_blocks=512, decode_bucket_b=4, seq_bucket=320,
                    sched=SchedulerConfig(max_batch_tokens=100_000,
                                          max_decode_batch=4,
                                          max_prefill_batch=1),
-                   incremental_decode=incremental, trace_decode=True),
+                   trace_decode=True),
         cfg=cfg, params=params, store=None)
-    reqs = _churny_requests(kb)
-    stats = eng.run(reqs)
-    return eng, stats, reqs
+
+
+def _per_request_logits(eng):
+    """rid -> the request's decode logits, step by step."""
+    out = {}
+    for step in eng.decode_trace:
+        for rid, lg in step.items():
+            out.setdefault(rid, []).append(lg)
+    return out
 
 
 def test_incremental_matches_rebuild(world):
+    """The churny schedule against the same requests submitted one at a
+    time, on the same (B, S) buckets: each request's per-step decode
+    logits and final pool KV are bit identical, while the churny run
+    absorbed every join and leave in the row map."""
     cfg, params, kb = world
-    eng_i, stats_i, reqs_i = _run(cfg, params, kb, incremental=True)
-    eng_r, stats_r, reqs_r = _run(cfg, params, kb, incremental=False)
+    eng_c = _engine(cfg, params)
+    reqs_c = _churny_requests(kb)
+    stats_c = eng_c.run(reqs_c)
+    eng_1 = _engine(cfg, params)
+    reqs_1 = _churny_requests(kb)
+    for r in reqs_1:
+        eng_1.run([r])
 
-    assert stats_i.completed == 6 and stats_i.failed == 0
-    assert stats_r.completed == 6 and stats_r.failed == 0
+    assert stats_c.completed == 6 and stats_c.failed == 0
+    assert eng_1.stats.completed == 6
+    assert eng_1.stats.decode_steps > stats_c.decode_steps
 
-    # membership churn was handled in place, not by rebuilding: the
-    # incremental engine rebuilt only to create the batch, the rebuild
-    # engine regathered on every join/leave
-    ci, cr = eng_i.counters, eng_r.counters
-    assert ci.decode_rebuilds == 1
-    assert cr.decode_rebuilds > ci.decode_rebuilds
-    assert ci.decode_joins >= 4            # joins absorbed without rebuild
-    assert ci.decode_leaves >= 5           # leaves masked the row in place
-    assert ci.decode_rows_recycled >= 1    # masked rows were reused
-    assert cr.decode_joins == 0 and cr.decode_leaves == 0
+    # membership churn was handled in place: the churny engine rebuilt
+    # only to create the batch
+    c = eng_c.counters
+    assert c.decode_rebuilds == 1
+    assert c.decode_joins >= 4             # joins absorbed without rebuild
+    assert c.decode_leaves >= 5            # leaves emptied the row in place
+    assert c.decode_rows_recycled >= 1     # emptied rows were reused
 
-    # identical decode trajectory: same number of steps, and per-step
-    # logits bit-identical for every live request
-    assert stats_i.decode_steps == stats_r.decode_steps
-    assert len(eng_i.decode_trace) == len(eng_r.decode_trace)
-    for step, (ti, tr) in enumerate(zip(eng_i.decode_trace,
-                                        eng_r.decode_trace)):
-        assert set(ti) == set(tr), f"step {step}: batch membership differs"
-        for rid in ti:
+    # identical decode trajectory per request, bit for bit
+    lc, l1 = _per_request_logits(eng_c), _per_request_logits(eng_1)
+    assert set(lc) == set(l1) == {r.rid for r in reqs_c}
+    for rid in lc:
+        assert len(lc[rid]) == len(l1[rid])
+        for step, (a, b) in enumerate(zip(lc[rid], l1[rid])):
             np.testing.assert_array_equal(
-                ti[rid], tr[rid],
-                err_msg=f"step {step}, rid {rid}: decode logits differ")
+                a, b, err_msg=f"rid {rid}, step {step}: logits differ")
 
     # identical final pool KV per request (gathered before free_table)
-    assert set(eng_i.final_kv) == set(eng_r.final_kv)
-    for rid in eng_i.final_kv:
-        ki, vi, pi = eng_i.final_kv[rid]
-        kr, vr, pr = eng_r.final_kv[rid]
-        np.testing.assert_array_equal(pi, pr)
-        np.testing.assert_array_equal(ki, kr)
-        np.testing.assert_array_equal(vi, vr)
+    assert set(eng_c.final_kv) == set(eng_1.final_kv)
+    for rid in eng_c.final_kv:
+        kc, vc, pc = eng_c.final_kv[rid]
+        k1, v1, p1 = eng_1.final_kv[rid]
+        np.testing.assert_array_equal(pc, p1)
+        np.testing.assert_array_equal(kc, k1)
+        np.testing.assert_array_equal(vc, v1)
 
     # and identical outputs, of course
-    for ri, rr in zip(reqs_i, reqs_r):
-        assert ri.state == State.DONE
-        assert ri.output_tokens == rr.output_tokens
+    for rc, r1 in zip(reqs_c, reqs_1):
+        assert rc.state == State.DONE
+        assert rc.output_tokens == r1.output_tokens
 
 
 def test_zero_burn_requeues_under_pool_pressure(world):
@@ -223,8 +234,8 @@ def test_preempted_requests_bit_identical_to_unpressured(world):
 
 
 def test_decode_batch_shape_growth_triggers_rebuild(world):
-    """A joiner that does not fit the row arena (S too small) must fall
-    back to a full rebuild rather than truncate its KV."""
+    """A joiner that does not fit the slot-index rows (S too small) must
+    fall back to a rebuild rather than truncate its KV."""
     cfg, params, kb = world
     eng = build_engine(
         EngineSpec(strategy="all", use_focus=False,

@@ -84,6 +84,7 @@ def _requests(kb, n=2, max_new=4, seed=5, shared_chunks=False):
      "tier_dtypes"),
     (dict(store=StoreSpec(hbm_bytes=0)), ValueError, "capacities"),
     (dict(store={"n_chunks": 5}), TypeError, "store"),
+    (dict(paged_decode=False), ValueError, "paged_decode"),
 ])
 def test_spec_validation_names_the_field(kw, err, match):
     with pytest.raises(err, match=match):

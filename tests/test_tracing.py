@@ -3,9 +3,8 @@
 * An engine served under the JAX profiler records the ``cc.*`` span
   tree of ``repro.tracing``'s docstring, each span inside its parent,
   and the server's engine thread records ``cc.dispatch``/``cc.idle``.
-* Every device->host read of a decode step is counted: a paged step
-  fetches its tokens and new KV together; an arena step reads the
-  logits, then the new token's K and V per layer per live row.
+* Every device->host read of a decode step is counted: a step fetches
+  its tokens and new KV together.
 * With the profiler off a span records nothing.
 """
 import glob
@@ -53,12 +52,11 @@ def _requests(cfg, n, max_new, seed=3):
         max_new_tokens=max_new, arrival_time=0.0) for i in range(n)]
 
 
-def _engine(world, paged=True, store=False):
+def _engine(world, store=False):
     cfg, params = world
     spec = EngineSpec(
         strategy="cachecraft" if store else "all", use_focus=False,
         pool_blocks=512, decode_bucket_b=4, seq_bucket=512,
-        paged_decode=paged,
         sched=SchedulerConfig(max_batch_tokens=100_000, max_decode_batch=4,
                               max_prefill_batch=2))
     return build_engine(spec, cfg=cfg, params=params,
@@ -148,13 +146,11 @@ def test_server_engine_thread_records_dispatch_and_idle(world, tmp_path):
             assert _parent(spans, i) is None
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "arena"])
-def test_decode_step_reads_two_arrays_paged_kv_per_row_arena(world, paged):
-    """A paged step makes one fetch of two arrays (tokens and new KV),
-    whatever the live rows and layers; an arena step reads the logits,
-    then K and V per pattern and tail layer per live row."""
+def test_decode_step_reads_two_arrays(world):
+    """A decode step makes one fetch of two arrays (tokens and new KV),
+    whatever the live rows and layers."""
     cfg, _ = world
-    eng = _engine(world, paged=paged)
+    eng = _engine(world)
     reqs = _requests(cfg, n=3, max_new=8)
     for r, n in zip(reqs, (4, 6, 8)):
         r.max_new_tokens = n
@@ -162,7 +158,6 @@ def test_decode_step_reads_two_arrays_paged_kv_per_row_arena(world, paged):
         eng.submit(r)
     while eng.scheduler.queue:                 # admit all three
         eng.step()
-    P = len(cfg.pattern)
     seen = set()
     while eng.decoding:                        # decode-only steps
         live = len(eng.decoding)
@@ -170,8 +165,7 @@ def test_decode_step_reads_two_arrays_paged_kv_per_row_arena(world, paged):
         assert not eng.scheduler.queue
         before = eng.counters.d2h_reads
         eng.step()
-        assert eng.counters.d2h_reads - before == \
-            (2 if paged else 1 + 2 * (P + cfg.n_tail) * live)
+        assert eng.counters.d2h_reads - before == 2
     assert seen == {1, 2, 3}
 
 

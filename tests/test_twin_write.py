@@ -37,7 +37,6 @@ def _engine(world, *, store=True, prefill_batch=1, pool_blocks=512,
     spec = EngineSpec(
         strategy="cachecraft" if store else "all", use_focus=False,
         pool_blocks=pool_blocks, decode_bucket_b=4, seq_bucket=128,
-        paged_decode=True,
         sched=SchedulerConfig(max_batch_tokens=100_000, max_decode_batch=4,
                               max_prefill_batch=prefill_batch,
                               preempt_after_iters=preempt_after))
